@@ -63,6 +63,26 @@ def test_snappy_native_available():
     assert native.available(), "native snappy failed to build"
 
 
+def test_native_stamp_covers_host_target(monkeypatch):
+    """The library's stamp hashes the host's -march=native target with the
+    sources: a checkout copied to a machine with another CPU builds its own
+    library instead of loading one built for this host."""
+    import subprocess
+
+    real = subprocess.run
+    base = native._stamp()
+    assert base == native._stamp()
+
+    def other_cpu(cmd, **kw):
+        out = real(cmd, **kw)
+        if "--help=target" in cmd:
+            out.stdout += b"  -mavx512f  [enabled]\n"
+        return out
+
+    monkeypatch.setattr(native.subprocess, "run", other_cpu)
+    assert native._stamp() != base
+
+
 def test_native_snappy_decodes_pyarrow_output():
     for data in _corpora():
         comp = pa.compress(data, codec="snappy", asbytes=True)

@@ -1010,3 +1010,40 @@ def test_fused_row_group_mode_matches_default():
         _compare_file(path)
     finally:
         dr._FUSE_RG = old
+
+
+@pytest.mark.parametrize("backend,env_dir", [
+    ("cpu", False), ("cpu", True), ("tpu", False), ("tpu", True)])
+def test_compile_cache_location(backend, env_dir, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR (which jax reads into its config) wins and
+    the code sets no other directory; unset, a TPU caches at the fixed
+    <checkout>/.jax_cache/ and the CPU caches nothing.  The size and time
+    thresholds apply wherever a cache is on."""
+    import os
+
+    import jax
+
+    from tpu_parquet import device_reader as dr
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.setattr(dr, "_CACHE_ENABLED", False)
+    monkeypatch.setattr(dr.jax, "default_backend", lambda: backend)
+    try:
+        jax.config.update(keys[0], str(tmp_path) if env_dir else None)
+        dr._enable_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+        if env_dir:
+            assert got == str(tmp_path)
+        elif backend == "tpu":
+            assert got == os.path.join(dr._CHECKOUT, ".jax_cache")
+        else:
+            assert not got
+        if got:
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.2
+            assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

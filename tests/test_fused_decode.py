@@ -1,10 +1,10 @@
 """Fused decode megakernel tests (ISSUE 13 / ROADMAP direction 2).
 
-The contract under test: the fused routes (`fused_plain`,
-`fused_narrow_snappy`) decode BIT-IDENTICALLY to the host reader across
-prefetch={0,4} and validate_crc on/off — the megakernels only fuse device
-passes, they never own different semantics — and degrade to their unfused
-twins (with a counter, never a crash) wherever they cannot claim a stream.
+The contract under test: the fused route (`fused_plain`) decodes
+BIT-IDENTICALLY to the host reader across prefetch={0,4} and validate_crc
+on/off — the megakernel only fuses device passes, it never owns different
+semantics — and degrades to its unfused twin (with a counter, never a
+crash) wherever it cannot claim a stream.
 On CPU the whole fused graph runs through the Pallas interpreter
 (TPQ_FUSE=1), so tier-1 proves the exact graph a TPU compiles.  The
 registry ``device`` section's ``device_passes`` counter is the structural
@@ -25,15 +25,11 @@ from tpu_parquet.format import CompressionCodec, FieldRepetitionType as FRT, Typ
 from tpu_parquet.reader import FileReader
 from tpu_parquet.schema.core import build_schema, data_column
 from tpu_parquet.ship import (
-    FUSED_ROUTES, ROUTE_FUSED_NARROW_SNAPPY, ROUTE_FUSED_PLAIN,
-    ROUTE_NARROW_SNAPPY, ROUTE_PLAIN, ROUTES, UNFUSED_OF, ChunkFacts,
-    ShipPlanner, fused_eligible, parse_route,
+    FUSED_ROUTES, ROUTE_FUSED_PLAIN, ROUTE_PLAIN, ROUTES, UNFUSED_OF,
+    ChunkFacts, ShipPlanner, fused_eligible, parse_route,
 )
 from tpu_parquet.writer import FileWriter, corrupt_page
 
-# group size chosen so the narrow transcode clears the planner's
-# MIN_COMPRESS_BYTES gate (narrowed k=2 bytes/value * 40k values = 80 KiB)
-# — the fused_narrow_snappy row must be PRICED, not just forceable
 N = 80_000
 ROWS_PER_GROUP = 40_000
 
@@ -41,15 +37,13 @@ ROWS_PER_GROUP = 40_000
 def _columns():
     rng = np.random.default_rng(23)
     return {
-        # date-like with run structure: narrow k=2 output is low-entropy
-        # AND snappy's matches reference nearby literals (shallow copy
-        # chains) — the fused narrow+snappy kernel's home turf
+        # date-like with run structure: the narrow routes' home turf
         "dates": np.repeat(19_000 + rng.integers(0, 1200, N // 50),
                            50).astype(np.int64),
         # full 63-bit range: every shrink route declines; the fused PLAIN
         # kernel's lane (the plain_int64 debt)
         "wide": rng.integers(-(1 << 62), 1 << 62, N),
-        # 32-bit lanes through both kernels
+        # 32-bit lanes through the kernel
         "cnt": rng.integers(0, 50_000, N).astype(np.int32),
         "rate": rng.uniform(0, 1, N).astype(np.float32),
         "dbl": np.repeat(rng.uniform(0.0, 1.0, N // 100), 100),
@@ -118,8 +112,8 @@ def test_fused_route_bit_identical(fused_file, route, prefetch, crc,
     r = _assert_device_matches(path, host, prefetch=prefetch,
                                validate_crc=crc)
     st = r.stats().as_dict()
-    # the forced fused route actually RAN where it could (dates always
-    # qualifies for both kernels on this file)
+    # the forced fused route actually RAN where it could (every column
+    # of this file is flat and 4 or 8 bytes wide)
     assert st["ship_routes"].get(route, {}).get("streams", 0) >= 1, \
         st["ship_routes"]
 
@@ -160,7 +154,7 @@ def test_fused_corrupt_page_containment(fused_file, tmp_path, monkeypatch):
     src, _ = fused_file
     path = str(tmp_path / "corrupt.parquet")
     shutil.copyfile(src, path)
-    # column 0 is `dates` — the stream both fused kernels claim
+    # column 0 is `dates` — a stream the fused kernel claims
     corrupt_page(path, row_group=1, column=0, page=0, mode="bitflip",
                  seed=3)
     monkeypatch.setenv("TPQ_FUSE", "1")
@@ -190,13 +184,9 @@ def test_planner_offers_fused_rows():
                    narrow_possible=True, flat=True)
     order, costs = p.plan(f)
     assert ROUTE_FUSED_PLAIN in costs
-    assert ROUTE_FUSED_NARROW_SNAPPY in costs
-    # no inter-stage HBM term: the fused device lane is the single pass,
-    # strictly below the unfused composite
     dev = p.device_costs(f, routes=costs)
-    assert dev[ROUTE_FUSED_NARROW_SNAPPY] < dev[ROUTE_NARROW_SNAPPY]
     # the spill-inclusive unfused prediction (fusion-win's bar) exceeds
-    # the fused model for both rows
+    # the fused model
     unf = p.unfused_device_costs(f, routes=costs)
     for fr in FUSED_ROUTES:
         assert unf[fr] > dev[fr]
@@ -232,13 +222,14 @@ def test_route_registry_is_single_table():
         assert fr in ROUTES
         assert UNFUSED_OF[fr] in ROUTES
     assert parse_route("fused_plain") == ROUTE_FUSED_PLAIN
-    assert parse_route(" fused_narrow_snappy ") == ROUTE_FUSED_NARROW_SNAPPY
+    assert parse_route(" fused_plain ") == ROUTE_FUSED_PLAIN
+    assert parse_route("fused_narrow_snappy") is None  # gone in PR 21
     assert parse_route("warp-speed") is None
     assert parse_route("") is None
     # the plan IR memoizes fused routes like any other (replay hint)
     plan = ScanPlan(row_groups=[])
-    plan.note_route(0, "a", ROUTE_FUSED_NARROW_SNAPPY, "fused")
-    assert plan.route_hint(0, "a") == ROUTE_FUSED_NARROW_SNAPPY
+    plan.note_route(0, "a", ROUTE_FUSED_PLAIN, "fused")
+    assert plan.route_hint(0, "a") == ROUTE_FUSED_PLAIN
 
 
 def test_forced_fused_on_ineligible_degrades(tmp_path, monkeypatch):
@@ -345,7 +336,7 @@ def test_doctor_fusion_win(tmp_path):
         "reader": {
             "host_seconds": 0.05, "staged_bytes": 1 << 20,
             "ship_routes": {
-                "fused_narrow_snappy": {
+                "fused_plain": {
                     "streams": 4, "logical": 4 << 20, "shipped": 1 << 20,
                     "predicted_s": 0.01, "predicted_device_s": 0.002,
                     "predicted_unfused_device_s": 0.02,
@@ -354,7 +345,7 @@ def test_doctor_fusion_win(tmp_path):
         },
         "device": {
             "dispatches": 4, "device_seconds": 0.005,
-            "routes": {"fused_narrow_snappy": {
+            "routes": {"fused_plain": {
                 "dispatches": 4, "device_seconds": 0.005,
                 "bytes_in": 4 << 20, "bytes_staged": 1 << 20,
                 "device_passes": 4}},
@@ -367,11 +358,11 @@ def test_doctor_fusion_win(tmp_path):
     rep = doctor_registry(tree)
     fw = rep.get("fusion_win")
     assert fw is not None
-    assert fw["route"] == "fused_narrow_snappy"
+    assert fw["route"] == "fused_plain"
     assert fw["speedup"] == pytest.approx(0.02 / 0.005, rel=1e-3)
     # a slower-than-predicted fused lane reports NO win
     worse = json.loads(json.dumps(tree))
-    worse["device"]["routes"]["fused_narrow_snappy"]["device_seconds"] = 0.5
+    worse["device"]["routes"]["fused_plain"]["device_seconds"] = 0.5
     assert doctor_registry(worse).get("fusion_win") is None
     # the CLI renders it
     p = tmp_path / "reg.json"
@@ -380,7 +371,7 @@ def test_doctor_fusion_win(tmp_path):
     assert cmd_doctor(argparse.Namespace(file=str(p), config=None),
                       out=buf) == 0
     out = buf.getvalue()
-    assert "fusion-win" in out and "fused_narrow_snappy" in out
+    assert "fusion-win" in out and "fused_plain" in out
 
 
 def test_fused_routes_ride_ship_feedback(fused_file, monkeypatch):
@@ -389,13 +380,13 @@ def test_fused_routes_ride_ship_feedback(fused_file, monkeypatch):
     section names the `fused` kernel family."""
     path, _ = fused_file
     monkeypatch.setenv("TPQ_FUSE", "1")
-    monkeypatch.setenv("TPQ_FORCE_ROUTE", ROUTE_FUSED_NARROW_SNAPPY)
+    monkeypatch.setenv("TPQ_FORCE_ROUTE", ROUTE_FUSED_PLAIN)
     with DeviceFileReader(path) as r:
         for _ in r.iter_row_groups():
             pass
         tree = r.obs_registry().as_dict()
     fb = tree["reader"]["ship_feedback"]["routes"]
-    rec = fb.get(ROUTE_FUSED_NARROW_SNAPPY)
+    rec = fb.get(ROUTE_FUSED_PLAIN)
     assert rec is not None
     assert rec["device_unfused_predicted_seconds"] is not None
     assert rec["device_unfused_predicted_seconds"] > 0

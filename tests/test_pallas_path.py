@@ -20,7 +20,9 @@ from tpu_parquet.writer import FileWriter
 def test_unpack_bp_groups_matches_host_unpack(width):
     import jax.numpy as jnp
 
-    from tpu_parquet.pallas_kernels import bp_groups_pad, unpack_bp_groups
+    from tpu_parquet.pallas_kernels import (
+        bp_groups_pad, bp_value_index, unpack_bp_groups,
+    )
 
     rng = np.random.default_rng(width)
     n = 5000
@@ -31,14 +33,18 @@ def test_unpack_bp_groups_matches_host_unpack(width):
     buf = np.zeros(gpad * width + 64, dtype=np.uint8)
     buf[: packed.nbytes] = packed
     out = unpack_bp_groups(jnp.asarray(buf), 0, width, gpad, interpret=True)
-    got = np.asarray(out)[:n].astype(np.uint64)
+    # value-major output: value j of group g at j * gpad + g
+    got = np.asarray(out)[bp_value_index(np.arange(n), gpad)]
+    got = got.astype(np.uint64)
     np.testing.assert_array_equal(got, vals)
 
 
 def test_unpack_bp_groups_nonzero_base():
     import jax.numpy as jnp
 
-    from tpu_parquet.pallas_kernels import bp_groups_pad, unpack_bp_groups
+    from tpu_parquet.pallas_kernels import (
+        bp_groups_pad, bp_value_index, unpack_bp_groups,
+    )
 
     rng = np.random.default_rng(0)
     n, width = 4096, 11
@@ -49,7 +55,8 @@ def test_unpack_bp_groups_nonzero_base():
     buf = np.zeros(base + gpad * width + 64, dtype=np.uint8)
     buf[base : base + packed.nbytes] = packed
     out = unpack_bp_groups(jnp.asarray(buf), base, width, gpad, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out)[:n].astype(np.uint64), vals)
+    got = np.asarray(out)[bp_value_index(np.arange(n), gpad)]
+    np.testing.assert_array_equal(got.astype(np.uint64), vals)
 
 
 def _mixed_run_values(rng, n, card):
@@ -149,6 +156,19 @@ def test_pallas_default_off_on_cpu(monkeypatch):
     assert _pallas_interpret_mode() is None
     monkeypatch.setenv("TPQ_PALLAS", "1")
     assert _pallas_interpret_mode() is True
+
+
+def test_interpret_mode_is_an_error_on_tpu(monkeypatch):
+    """The interpreter is the CPU's Pallas path; on a TPU every kernel
+    compiles natively, and asking for the interpreter there raises."""
+    from tpu_parquet import pallas_kernels as pk
+
+    assert pk.resolve_interpret(None) is True  # CPU conftest backend
+    assert pk.resolve_interpret(False) is False
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    assert pk.resolve_interpret(None) is False
+    with pytest.raises(RuntimeError, match="interpret"):
+        pk.resolve_interpret(True)
 
 
 def test_pallas_plan_declines_pathological_runs(tmp_path, monkeypatch):

@@ -126,7 +126,7 @@ def test_graft_entry_single_chip():
 
     # trace under x64: the example args are int64 metadata, and a no-x64
     # jit boundary would downcast them before the kernels' scoped_x64
-    # contexts apply (mixed i32/i64 jaxpr on 0.4.x jax)
+    # contexts apply
     with enable_x64():
         out = jax.jit(fn)(*args)
     jax.block_until_ready(out)
@@ -256,3 +256,31 @@ def test_process_local_column_single_process(mesh, tmp_path):
         arr, valid = par.process_local_column(r, "v", mesh)
     assert valid == 1000
     np.testing.assert_array_equal(np.asarray(arr), vals)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_scan_files_stays_on_default_device(tmp_path, prefetch, monkeypatch):
+    """Under ``jax.default_device(d)`` a scan stages AND decodes on d: jax's
+    default device is thread-local, and the staging worker used to put
+    every buffer on device 0 (a cross-device copy per row group on a
+    multi-chip host)."""
+    from tpu_parquet import device_reader as dr
+
+    path, _ = _write_span_file(tmp_path, rows=20_000, rg_rows=7000)
+    dev = jax.devices()[3]
+    staged = set()
+    real = dr._run_plans
+
+    def spy(plans, buf_dev, timer=None):
+        if buf_dev is not None:
+            staged.update(buf_dev.devices())
+        return real(plans, buf_dev, timer)
+
+    monkeypatch.setattr(dr, "_run_plans", spy)
+    # small strips: the row group streams through the staging worker
+    monkeypatch.setattr(dr._RowGroupStager, "STRIP", 4096)
+    with jax.default_device(dev):
+        groups = list(dr.scan_files([path], prefetch=prefetch))
+    assert staged == {dev}
+    assert {d for g in groups for c in g.values()
+            for d in c.values.devices()} == {dev}

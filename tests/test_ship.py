@@ -168,6 +168,45 @@ def test_planner_env_overrides(monkeypatch):
         ShipPlanner(force="warp")
 
 
+@pytest.mark.parametrize("lane", [
+    ("LINK_MBPS", "device_link_mbps", "TPQ_LINK_MBPS", "link_mbps"),
+    ("DEVICE_RESOLVE_MBPS", "device_resolve_mbps", "TPQ_DEVICE_MBPS",
+     "device_mbps"),
+], ids=["link", "device"])
+@pytest.mark.parametrize("kind", ["cpu", "TPU v5 lite", "TPU v9 unknown"])
+def test_rate_keyed_by_device_kind(kind, lane, monkeypatch):
+    """Without the env override the planner takes the measured row of the
+    device's kind; a kind nobody measured raises instead of guessing."""
+    from tpu_parquet import ship
+
+    table, fn, env, attr = lane
+    monkeypatch.delenv(env, raising=False)
+    real = getattr(ship, fn)
+    monkeypatch.setattr(ship, fn, lambda: real(kind))
+    if kind not in getattr(ship, table):
+        with pytest.raises(ValueError, match=kind):
+            ShipPlanner()
+        monkeypatch.setenv(env, "800")  # the override still wins
+        assert getattr(ShipPlanner(), attr) == 800.0
+    else:
+        assert getattr(ShipPlanner(), attr) == getattr(ship, table)[kind]
+
+
+@pytest.mark.parametrize("comp", [0, 4 << 20])
+def test_v5e_rows_ship_lineitem_doubles_plain(comp):
+    """With the v5e rows, an 8 MB PLAIN double chunk of SF1 lineitem (lazy
+    snappy pages or none) ships plain: on that chip the device snappy
+    resolve measured ~6.8 MB/s, and pricing it at the CPU row's 3000
+    made the warm SF1 scan 3.6x slower (PR 21)."""
+    from tpu_parquet import ship
+
+    kind = "TPU v5 lite"
+    p = ShipPlanner(link_mbps=ship.LINK_MBPS[kind],
+                    device_mbps=ship.DEVICE_RESOLVE_MBPS[kind], fuse=True)
+    f = ChunkFacts(logical=8 << 20, width=8, comp_bytes=comp, native=True)
+    assert p.routes(f)[0] == ROUTE_PLAIN
+
+
 # ---------------------------------------------------------------------------
 # route bit-identity (the acceptance-criteria matrix)
 # ---------------------------------------------------------------------------

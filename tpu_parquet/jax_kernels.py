@@ -38,12 +38,7 @@ import jax.numpy as jnp
 # of the call: traces happen with 64-bit lanes on, returned arrays keep their
 # 64-bit dtypes, and the caller's global x64 setting is never touched.
 
-# the scoped x64 context manager moved between jax releases: newer jax exposes
-# it as ``jax.enable_x64``, older releases only as ``jax.experimental.enable_x64``
-# (same signature; accepts an optional bool).  Resolve once at import.
-enable_x64 = getattr(jax, "enable_x64", None)
-if enable_x64 is None:  # pragma: no cover - depends on installed jax
-    from jax.experimental import enable_x64  # noqa: F401
+enable_x64 = jax.enable_x64
 
 
 def scoped_x64(fn):

@@ -54,7 +54,7 @@ LEDGER_VERSION = 1
 # flip is visibly a different experiment.
 _ENV_KEYS = (
     "TPQ_LINK_MBPS", "TPQ_FORCE_ROUTE", "TPQ_TRACE", "TPQ_SAMPLE_MS",
-    "TPQ_DEVICE_SNAPPY", "TPQ_COMPILE_CACHE", "TPQ_FUSE_RG", "TPQ_FUSE",
+    "TPQ_DEVICE_SNAPPY", "TPQ_FUSE_RG", "TPQ_FUSE",
     "TPQ_PALLAS",
     "TPQ_DEFER_DICT_CHECK", "TPQ_DEVICE_MBPS", "TPQ_DEVICE_TIMING",
     "TPQ_XPROF", "TPQ_SERVE_CONCURRENCY", "TPQ_SERVE_QUEUE",

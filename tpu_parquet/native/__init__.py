@@ -2,9 +2,11 @@
 
 Built lazily with g++ the first time they're needed (no pip/cmake dependency at
 import time); the shared object is cached next to the sources and rebuilt when any
-source file changes (content-hash stamp).  Everything here is optional: each consumer
-has a pure-Python fallback, so the framework still works — slower — without a C++
-toolchain.
+source file changes or the host's compiler target differs (the stamp hashes both:
+the build uses ``-march=native``, so a checkout copied to another machine builds
+its own library instead of loading one that may use instructions its CPU lacks).
+Every consumer has a pure-Python fallback, so the framework still works — slower —
+without a C++ toolchain; a failed load says so once, as a warning.
 """
 
 from __future__ import annotations
@@ -24,20 +26,35 @@ _lib = None
 _load_failed = False
 
 
-def _source_hash() -> str:
+_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17", "-march=native"]
+
+
+def _stamp() -> str:
+    """Hash of the sources, the flags, and what ``-march=native`` resolves
+    to on this host (every target option g++ would enable)."""
     h = hashlib.sha256()
     for src in _SOURCES:
         with open(os.path.join(_DIR, src), "rb") as f:
             h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(subprocess.run(
+        ["g++", "-march=native", "-Q", "--help=target"], check=True,
+        capture_output=True, timeout=60).stdout)
     return h.hexdigest()[:16]
 
 
 def _build(lib_path: str) -> None:
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-march=native",
-        "-o", lib_path,
-    ] + [os.path.join(_DIR, s) for s in _SOURCES]
-    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    # build beside the target, then rename: concurrent processes (test
+    # workers) never load a half-written library
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, "-o", tmp] + [
+        os.path.join(_DIR, s) for s in _SOURCES]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load():
@@ -49,13 +66,14 @@ def load():
         if _lib is not None or _load_failed:
             return _lib
         try:
-            stamp = _source_hash()
+            stamp = _stamp()
             lib_path = os.path.join(_DIR, f"{_LIB_BASENAME}.{stamp}")
             if not os.path.exists(lib_path):
                 _build(lib_path)
                 # drop stale builds
                 for f in os.listdir(_DIR):
-                    if f.startswith(_LIB_BASENAME) and not f.endswith(stamp):
+                    if (f.startswith(_LIB_BASENAME) and not f.endswith(stamp)
+                            and not f.endswith(".tmp")):
                         try:
                             os.unlink(os.path.join(_DIR, f))
                         except OSError:
@@ -155,8 +173,13 @@ def load():
                 ctypes.c_void_p, c_ll, ctypes.c_int, c_ll, ctypes.c_void_p,
             ]
             _lib = lib
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — every consumer falls back
             _load_failed = True
+            import warnings
+
+            warnings.warn(f"native library unavailable ({e!r}); using the "
+                          f"pure-Python fallbacks", RuntimeWarning,
+                          stacklevel=2)
     return _lib
 
 

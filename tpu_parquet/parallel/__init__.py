@@ -37,19 +37,9 @@ from .. import jax_kernels as K
 from ..jax_kernels import scoped_x64
 from ..jax_decode import HybridMeta, DeltaMeta, parse_hybrid_meta, parse_delta_meta, _bucket, _SLACK
 
-# shard_map moved and renamed a kwarg across jax releases: newer jax exposes
-# ``jax.shard_map(..., check_vma=)``, 0.4.x only
-# ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Resolve once.
-if hasattr(jax, "shard_map"):
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return _exp_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def _shard_map(fn, mesh, in_specs, out_specs):
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 __all__ = [
     "make_mesh",
