@@ -343,17 +343,16 @@ def bench_device(path, rows, name=""):
     if tracer is not None:
         log(f"  trace artifact: {tracer.write(registry=reg)}")
     ship["obs"] = reg.as_dict()
-    # the per-route device completion lane (TPQ_DEVICE_TIMING, default on):
-    # smoke exercises this section end to end, and the ledger diff
-    # attributes device regressions to a specific route from it
+    # the per-route device dispatch counters (device time per kernel
+    # family comes from a profiler capture)
     dev = ship["obs"].get("device")
     if dev:
         log(f"  device lanes: dispatches={dev.get('dispatches')} "
-            f"device_seconds={dev.get('device_seconds')} "
+            f"bytes_out={dev.get('bytes_out')} "
             f"routes={sorted((dev.get('routes') or {}))} "
-            f"h2d_s={(dev.get('h2d') or {}).get('device_seconds')}")
+            f"h2d_bytes={(dev.get('h2d') or {}).get('bytes')}")
     else:
-        log("  device lanes: n/a (timing lane disabled)")
+        log("  device lanes: n/a")
     return samples, ship
 
 
@@ -2465,7 +2464,7 @@ def main(argv=None):
     shutdown_default_engine()
     leaked = [t.name for t in threading.enumerate()
               if t.name.startswith(("tpq-sampler", "tpq-watchdog",
-                                    "tpq-devtimer", "tpq-hedge",
+                                    "tpq-hedge",
                                     "tpq-serve", "tpq-fetch",
                                     "tpq-metricsdump", "tpq-spool"))]
     if leaked:

@@ -1,16 +1,16 @@
-"""Device-lane observability tests (ISSUE 9).
+"""Device-lane observability tests.
 
-Covers the tentpole end to end: the completion-side per-route device timing
-lane (``TPQ_DEVICE_TIMING``: DeviceStats golden keys, registry ``device``
-section merge paths incl. a 2-OS-process round trip, the <3% disabled-path
-overhead guard, the stage/dispatch split replacing the double-counted
+Covers the per-route / per-kernel-family dispatch counters (DeviceStats
+golden keys, registry ``device`` section merge paths incl. a 2-OS-process
+round trip, the stage/dispatch split replacing the double-counted
 ``device_seconds`` scalar), HBM residency accounting on ``AllocTracker``
 (sampler track + flight dump watermark), the planner's device-lane feedback
 (``ship.device_costs`` / ``recalibrate_device_mbps``, ``ship_feedback``
 device lane null contract, doctor's ``h2d-bound`` sibling and dominant
-route/kernel naming), graceful degradation on artifacts predating the
-``device`` section, the CPU-only/no-backend drop path, and the bounded
-``TPQ_XPROF`` capture window.
+route/kernel naming on older trees that carry device seconds), graceful
+degradation on artifacts predating the ``device`` section, and the bounded
+``TPQ_XPROF`` capture window.  Device time itself comes from a profiler
+capture (tests/test_profiler_spans.py).
 """
 
 import io
@@ -18,8 +18,6 @@ import json
 import os
 import subprocess
 import sys
-import threading
-import time
 
 import numpy as np
 import pytest
@@ -61,27 +59,27 @@ def _device_stats():
     from tpu_parquet.device_reader import DeviceStats
 
     ds = DeviceStats()
-    ds.note_dispatch("plain", "plain", 0.01, bytes_in=1000, bytes_staged=1000)
-    ds.note_dispatch("device_snappy", "snappy_resolve", 0.03,
-                     bytes_in=4000, bytes_staged=1500)
-    ds.note_h2d(0.005, 2500)
+    ds.note_dispatch("plain", "plain", bytes_out=1000, bytes_staged=1000)
+    ds.note_dispatch("device_snappy", "snappy_resolve", bytes_out=4000,
+                     bytes_staged=1500)
+    ds.note_h2d(2500)
     return ds
 
 
 def test_device_stats_as_dict_golden_keys():
     d = _device_stats().as_dict()
-    assert set(d) == {"dispatches", "device_seconds", "routes", "kernels",
-                      "h2d"}
+    assert set(d) == {"dispatches", "bytes_out", "routes", "kernels", "h2d"}
     assert d["dispatches"] == 2
-    assert d["device_seconds"] == pytest.approx(0.04)
+    assert d["bytes_out"] == 5000
     assert set(d["routes"]) == {"plain", "device_snappy"}
+    counters = {"dispatches", "bytes_out", "bytes_staged", "device_passes"}
     for r in d["routes"].values():
-        assert set(r) == {"dispatches", "device_seconds", "bytes_in",
-                          "bytes_staged", "device_passes"}
+        assert set(r) == counters
     assert set(d["kernels"]) == {"plain", "snappy_resolve"}
     for k in d["kernels"].values():
-        assert set(k) == {"dispatches", "device_seconds"}
-    assert set(d["h2d"]) == {"transfers", "device_seconds", "bytes"}
+        assert set(k) == counters
+    assert d["kernels"]["snappy_resolve"]["bytes_staged"] == 1500
+    assert set(d["h2d"]) == {"transfers", "bytes"}
     assert d["h2d"]["bytes"] == 2500
     json.dumps(d)  # artifact-ready
 
@@ -97,9 +95,8 @@ def test_registry_device_section_merge_from_and_dict():
     t = a.as_dict()["device"]
     assert t["dispatches"] == 4
     assert t["routes"]["plain"]["dispatches"] == 2
-    assert t["routes"]["device_snappy"]["bytes_in"] == 8000
-    assert t["kernels"]["snappy_resolve"]["device_seconds"] == (
-        pytest.approx(0.06))
+    assert t["routes"]["device_snappy"]["bytes_out"] == 8000
+    assert t["kernels"]["snappy_resolve"]["bytes_out"] == 8000
     assert t["h2d"]["transfers"] == 2
     # serialized (cross-process) merge stacks on top
     a.merge_dict(b.as_dict())
@@ -114,8 +111,8 @@ from tpu_parquet.obs import StatsRegistry
 
 ds = DeviceStats()
 for i in range(100):
-    ds.note_dispatch("narrow", "narrow", 1e-4, bytes_in=10, bytes_staged=5)
-ds.note_h2d(1e-3, 64)
+    ds.note_dispatch("narrow", "narrow", bytes_out=10, bytes_staged=5)
+ds.note_h2d(64)
 reg = StatsRegistry()
 reg.add_device(ds)
 print(json.dumps(reg.as_dict()))
@@ -136,12 +133,10 @@ def test_two_process_device_merge_roundtrip():
         reg.merge_dict(o)
     t = reg.as_dict()["device"]
     assert t["routes"]["narrow"]["dispatches"] == 200
-    assert t["routes"]["narrow"]["device_seconds"] == pytest.approx(
-        200 * 1e-4, rel=1e-6)
+    assert t["routes"]["narrow"]["bytes_out"] == 2000
+    assert t["routes"]["narrow"]["bytes_staged"] == 1000
     assert t["kernels"]["narrow"]["dispatches"] == 200
-    assert t["h2d"] == {"transfers": 2,
-                        "device_seconds": pytest.approx(2e-3),
-                        "bytes": 128}
+    assert t["h2d"] == {"transfers": 2, "bytes": 128}
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +207,13 @@ def test_ship_feedback_device_lane_null_until_measured():
     assert r["device_measured_seconds"] is None
     assert r["device_error_ratio"] is None
     json.dumps(r)
-    # the device section arrives (a later merge): the lane fills in
+    # a counter-only device section (every tree now) measures no seconds
+    reg.add_device(_device_stats())
+    assert reg.ship_feedback()["routes"]["plain"][
+        "device_measured_seconds"] is None
+    # an older tree's timed device section arrives: the lane fills in
+    reg = StatsRegistry()
+    reg.add_reader(rs)
     reg.add_device({"routes": {"plain": {"dispatches": 1,
                                          "device_seconds": 0.001,
                                          "bytes_in": 100,
@@ -417,15 +418,16 @@ def test_pipelined_run_keeps_lanes_distinct(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the timing lane end to end (device section, trace table, doctor verdict)
+# the device section end to end (registry counters, trace table, doctor)
 # ---------------------------------------------------------------------------
 
 def test_device_section_end_to_end_with_doctor(tmp_path):
-    """Acceptance criterion: on a traced run the registry carries a device
-    section whose routes mirror the ship routes, ship_feedback returns a
-    populated device lane per route, `pq_tool trace` prints device lanes in
-    the p50/p95 table, and `pq_tool doctor` names the dominant device route
-    with measured seconds and an error ratio."""
+    """On a traced run the registry carries a device section whose routes
+    mirror the ship routes, counted at dispatch: dispatches, the bytes the
+    programs return and the bytes staged, per route and per kernel family.
+    It carries no device seconds (those come from a profiler capture), so
+    ship_feedback's device lane stays null, `pq_tool trace` renders, and
+    `pq_tool doctor` prints `device: n/a`."""
     from tpu_parquet.cli import pq_tool
     from tpu_parquet.device_reader import DeviceFileReader
 
@@ -438,108 +440,28 @@ def test_device_section_end_to_end_with_doctor(tmp_path):
         st = r.stats().as_dict()
     dev = tree["device"]
     assert dev is not None and dev["dispatches"] > 0
-    # every timed route is a route the planner actually chose — plus the
+    # every counted route is a route the planner actually chose — plus the
     # default "plain" attribution for columns with no value-stream ship
     # record (dict-index/levels-only plans)
-    assert set(dev["routes"]) <= set(st["ship_routes"]) | {"plain", "h2d"}
+    assert set(dev["routes"]) <= set(st["ship_routes"]) | {"plain"}
     assert dev["h2d"]["transfers"] > 0
-    assert dev["h2d"]["bytes"] > 0
-    for c in dev["routes"].values():
-        assert c["device_seconds"] > 0.0
-    assert dev["kernels"], "kernel-family attribution missing"
-    # ship_feedback: populated device lane per route (the timed ones)
+    assert dev["h2d"]["bytes"] == st["staged_bytes"]
+    for c in list(dev["routes"].values()) + list(dev["kernels"].values()):
+        assert c["bytes_out"] > 0 and "device_seconds" not in c
+    assert sum(c["bytes_staged"] for c in dev["routes"].values()) \
+        == st["staged_bytes"]
+    assert sum(c["dispatches"] for c in dev["kernels"].values()) \
+        == dev["dispatches"]
     fb = tree["reader"]["ship_feedback"]["routes"]
-    timed = [r for r in fb.values()
-             if r["device_measured_seconds"] is not None]
-    assert timed, "no route carries a measured device lane"
-    # the trace artifact carries device.<route> spans -> p50/p95 table rows
+    assert all(r["device_measured_seconds"] is None for r in fb.values())
     out = io.StringIO()
     args = pq_tool.build_parser().parse_args(["trace", tp])
     assert args.func(args, out=out) == 0
-    text = out.getvalue()
-    assert "device." in text
-    # doctor names the dominant device route with its error ratio
     out = io.StringIO()
     args = pq_tool.build_parser().parse_args(["doctor", tp])
     assert args.func(args, out=out) == 0
-    text = out.getvalue()
-    rep = doctor_registry(tree)
-    assert f"device: dominant route {rep['device']['dominant_route']!r}" \
-        in text
-    assert rep["device"]["measured_seconds"] > 0.0
-
-
-def test_timing_lane_env_off(tmp_path, monkeypatch):
-    """TPQ_DEVICE_TIMING=0: no device section, no timer thread, reads
-    unchanged."""
-    from tpu_parquet.device_reader import DeviceFileReader
-
-    monkeypatch.setenv("TPQ_DEVICE_TIMING", "0")
-    path = _write_ints(str(tmp_path / "off.parquet"), rows=30_000, groups=1)
-    with DeviceFileReader(path) as r:
-        rows = 0
-        for cols in r.iter_row_groups():
-            rows += cols["v"].num_values
-        tree = r.obs_registry().as_dict()
-    assert rows == 30_000
-    assert tree["device"] is None
-    assert not [t for t in threading.enumerate()
-                if t.name.startswith("tpq-devtimer")]
-
-
-def test_timing_lane_drops_without_backend(tmp_path, monkeypatch, caplog):
-    """CPU-only/no-backend satellite: when no jax device is available the
-    timing lane (and its sampler track) drop with ONE warning and the read
-    stays green."""
-    import logging
-
-    import tpu_parquet.device_reader as dr
-    from tpu_parquet import obs
-
-    def _no_backend(*a, **k):
-        raise RuntimeError("no backend")
-
-    monkeypatch.setattr(dr.jax, "devices", _no_backend)
-    obs._env_warned.discard(("TPQ_DEVICE_TIMING", "<no jax device>"))
-    with caplog.at_level(logging.WARNING, logger="tpu_parquet.obs"):
-        assert dr._device_timing_enabled() is False
-        assert dr._device_timing_enabled() is False  # warned ONCE
-    warns = [rec for rec in caplog.records
-             if "TPQ_DEVICE_TIMING" in rec.getMessage()]
-    assert len(warns) == 1
-    # restore jax.devices (this CPU test still needs the backend to decode)
-    # and drop just the probe: the reader must construct, skip the lane,
-    # and read green
-    monkeypatch.undo()
-    monkeypatch.setattr(dr, "_device_timing_enabled", lambda: False)
-    path = _write_ints(str(tmp_path / "nodev.parquet"), rows=30_000,
-                       groups=1)
-    with dr.DeviceFileReader(path) as r:
-        assert r._device_timer.enabled is False
-        for _ in r.iter_row_groups():
-            pass
-        assert r.obs_registry().as_dict()["device"] is None
-
-
-def test_timer_thread_joins_on_close(tmp_path):
-    from tpu_parquet.device_reader import DeviceFileReader
-
-    path = _write_ints(str(tmp_path / "join.parquet"), rows=30_000, groups=1)
-    with DeviceFileReader(path) as r:
-        for _ in r.iter_row_groups():
-            pass
-        assert r._device_stats.progress()["dispatches"] >= 0
-    deadline = time.monotonic() + 5.0
-    while time.monotonic() < deadline:
-        if not [t for t in threading.enumerate()
-                if t.name.startswith("tpq-devtimer")]:
-            break
-        time.sleep(0.01)
-    assert not [t for t in threading.enumerate()
-                if t.name.startswith("tpq-devtimer")]
-    # a submit after close is dropped, never respawns the thread
-    r._device_timer.submit("dispatch", "plain", "plain", None, 0.0)
-    assert r._device_timer._thread is None
+    assert "device: n/a" in out.getvalue()
+    assert "device" not in doctor_registry(tree)
 
 
 # ---------------------------------------------------------------------------
@@ -594,119 +516,11 @@ def test_device_residency_in_sampler_tracks_and_flight_dump(tmp_path):
                     if e.get("ph") == "C" and e.get("name") == "alloc_bytes"]
     assert alloc_tracks
     assert any("device_peak" in (e.get("args") or {}) for e in alloc_tracks)
-    # the device timing track rode the same sampler
+    # the device counter track rode the same sampler
     dev_tracks = [e for e in doc["traceEvents"]
                   if e.get("ph") == "C" and e.get("name") == "device"]
     assert dev_tracks
     assert st.staged_bytes > 0
-
-
-# ---------------------------------------------------------------------------
-# overhead guard: the disabled timing lane costs <3% (tier-1)
-# ---------------------------------------------------------------------------
-
-def test_disabled_device_timing_overhead_under_3_percent():
-    """The tier-1 guard pattern (paired adjacent differences, median): the
-    hot loop calling a DISABLED _DeviceTimer.submit per iteration vs the
-    identical loop without it must differ by <3%."""
-    import gc
-
-    from tpu_parquet.device_reader import DeviceStats, _DeviceTimer
-
-    gc.collect()
-    gc.disable()
-    timer = _DeviceTimer(DeviceStats(), tracer=None, enabled=False)
-    rng = np.random.default_rng(0)
-    data = rng.integers(0, 1 << 40, 300_000)
-
-    def work():
-        return np.sort(data).sum()
-
-    def once(with_timer):
-        t0 = time.perf_counter()
-        if with_timer:
-            work()
-            timer.submit("dispatch", "plain", "plain", None,
-                         t0, bytes_in=0, bytes_staged=0)
-        else:
-            work()
-        return time.perf_counter() - t0
-
-    try:
-        for _ in range(3):
-            once(True), once(False)
-        base, obs = [], []
-        for _ in range(80):
-            obs.append(once(True))
-            base.append(once(False))
-    finally:
-        gc.enable()
-    diffs = sorted(o - b for o, b in zip(obs, base))
-    med_diff = diffs[len(diffs) // 2]
-    med_base = sorted(base)[len(base) // 2]
-    overhead = med_diff / med_base
-    assert overhead < 0.03, f"disabled device-timing overhead {overhead:.2%}"
-    assert timer._thread is None  # disabled lane never starts a thread
-
-
-def test_worker_serializes_overlapping_intervals():
-    """Per-entry intervals anchor at max(own dispatch, previous
-    completion): three entries dispatched at the same instant must
-    partition the elapsed device lane (~1x), never sum to ~3x it."""
-    from tpu_parquet.device_reader import DeviceStats, _DeviceTimer
-
-    stats = DeviceStats()
-    timer = _DeviceTimer(stats, tracer=None, enabled=True)
-    t0 = time.perf_counter() - 0.5  # all three "dispatched" 0.5s ago
-    for route in ("plain", "narrow", "plain"):
-        timer.submit("dispatch", route, "plain", None, t0, bytes_in=1)
-    timer.drain(timeout=5.0)
-    timer.stop()
-    total = stats.as_dict()["device_seconds"]
-    assert 0.4 < total < 0.7, total  # ~0.5s once, not ~1.5s
-
-
-def test_fused_path_times_one_entry_per_call(tmp_path):
-    """TPQ_FUSE_RG=1 runs ONE executable per row group: the timing lane
-    must bank one entry per fused call (per-plan submissions sharing the
-    fused t0 would each count the whole wall, ~N_plans x overcount), and a
-    mid-session obs_registry() read must drain the completion queue first
-    (never observe 1 of a group's dispatches because the worker is still
-    blocking on the rest)."""
-    code = r"""
-import sys
-sys.path.insert(0, sys.argv[1])
-import numpy as np
-from tpu_parquet.format import FieldRepetitionType as FRT, Type
-from tpu_parquet.schema.core import build_schema, data_column
-from tpu_parquet.writer import FileWriter
-from tpu_parquet.device_reader import DeviceFileReader
-
-rng = np.random.default_rng(0)
-schema = build_schema([data_column("v", Type.INT64, FRT.REQUIRED),
-                       data_column("w", Type.INT32, FRT.REQUIRED)])
-path = sys.argv[2]
-with FileWriter(path, schema, row_group_size=1) as w:
-    for _ in range(3):
-        w.write_columns({"v": rng.integers(0, 1 << 40, 20_000),
-                         "w": rng.integers(0, 1000, 20_000)
-                              .astype(np.int32)})
-        w.flush_row_group()
-with DeviceFileReader(path) as r:
-    for _ in r.iter_row_groups():
-        pass
-    dev = r.obs_registry().as_dict()["device"]
-assert dev["dispatches"] == 3, dev   # one per fused call, drained
-assert dev["h2d"]["transfers"] == 3, dev
-print("ok")
-"""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", TPQ_FUSE_RG="1")
-    res = subprocess.run(
-        [sys.executable, "-c", code, REPO_ROOT,
-         str(tmp_path / "fuse.parquet")],
-        capture_output=True, text=True, timeout=240, env=env)
-    assert res.returncode == 0, (res.stdout[-800:], res.stderr[-800:])
-    assert res.stdout.strip().endswith("ok")
 
 
 def test_residency_pending_vs_outstanding(tmp_path):
@@ -717,11 +531,8 @@ def test_residency_pending_vs_outstanding(tmp_path):
     path = _write_ints(str(tmp_path / "pend.parquet"), rows=30_000, groups=1)
     with DeviceFileReader(path) as r:
         prepared = r._prepare_row_group(0)
-        import time as _t
-
-        t0 = _t.perf_counter()
         buf = prepared[2].stage()
-        r._note_staged(prepared[2], buf, t0)
+        r._note_staged(prepared[2])
         staged = prepared[2].total
         assert r.alloc.device_snapshot()[0] == staged
         # finalize BEFORE dispatch: the pending buffer must survive
@@ -739,6 +550,8 @@ def test_residency_pending_vs_outstanding(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_xprof_window_captures_once(tmp_path, monkeypatch):
+    import jax
+
     import tpu_parquet.device_reader as dr
 
     xdir = str(tmp_path / "xprof")
@@ -748,7 +561,8 @@ def test_xprof_window_captures_once(tmp_path, monkeypatch):
     with dr.DeviceFileReader(path) as r:
         for _ in r.iter_row_groups():
             pass
-    assert not dr._XPROF_ACTIVE  # window closed with the scan
+    # the window closed with the scan
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     files = [os.path.join(root, f)
              for root, _, fs in os.walk(xdir) for f in fs]
     assert files, "no xprof artifact written"
@@ -756,13 +570,15 @@ def test_xprof_window_captures_once(tmp_path, monkeypatch):
     with dr.DeviceFileReader(path) as r:
         for _ in r.iter_row_groups():
             pass
-    assert not dr._XPROF_ACTIVE
+    assert not jax.profiler.TraceAnnotation.is_enabled()
 
 
 def test_xprof_window_covers_scan_files(tmp_path, monkeypatch):
     """scan_files drives _scan_pipeline directly (never iter_row_groups),
     so it must own its own capture window — the multi-file runs the
     feature targets."""
+    import jax
+
     import tpu_parquet.device_reader as dr
 
     xdir = str(tmp_path / "xprof_scan")
@@ -772,6 +588,6 @@ def test_xprof_window_covers_scan_files(tmp_path, monkeypatch):
                          groups=1, seed=i) for i in range(2)]
     n = sum(1 for _ in dr.scan_files(paths))
     assert n == 2
-    assert not dr._XPROF_ACTIVE
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     files = [f for _, _, fs in os.walk(xdir) for f in fs]
     assert files, "scan_files wrote no xprof artifact"

@@ -152,6 +152,32 @@ def test_device_request_matches_host(files):
     np.testing.assert_array_equal(got, host["a"].values)
 
 
+def test_device_request_holds_its_slot_until_device_work_is_done(
+        files, monkeypatch):
+    """A worker waits for each file's device arrays before it moves on, so
+    the service's concurrency bounds the device work in flight."""
+    import dataclasses
+
+    import jax
+
+    from tpu_parquet.serve import service
+
+    waited = []
+    real = service._wait_on_device
+
+    def spy(cols):
+        real(cols)
+        arrays = [getattr(p, f.name) for parts in cols.values()
+                  for p in parts for f in dataclasses.fields(p)]
+        waited.append(all(a.is_ready() for a in arrays
+                          if isinstance(a, jax.Array)))
+
+    monkeypatch.setattr(service, "_wait_on_device", spy)
+    with ScanService(concurrency=2) as svc:
+        svc.scan(ScanRequest(files[:2], device=True))
+    assert waited == [True, True]  # one wait per file, arrays ready
+
+
 def test_row_filter_request(files):
     from tpu_parquet.predicate import parse_filter
 

@@ -518,10 +518,11 @@ def cmd_doctor(args, out=sys.stdout) -> int:
                 f"({fw['speedup']:.2f}x) — the fused megakernel beats the "
                 f"staged chain; keep TPQ_FUSE on for this workload\n")
     else:
-        # records predating the device registry section (or runs with
-        # TPQ_DEVICE_TIMING=0): explicitly n/a, never a KeyError
-        out.write("device: n/a (no device section — record predates device "
-                  "timing, or TPQ_DEVICE_TIMING=0)\n")
+        # no device seconds in the tree (every tree since device time
+        # moved to the profiler, and records predating the device
+        # section): explicitly n/a, never a KeyError
+        out.write("device: n/a (no device seconds in this tree; device "
+                  "time per kernel family comes from a profiler capture)\n")
     ct = rep.get("cache")
     if ct:
         top = ct.get("top_evict_file")

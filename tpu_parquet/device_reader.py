@@ -27,7 +27,6 @@ from __future__ import annotations
 import functools
 import os
 import threading
-import time
 from contextlib import nullcontext as _noop_ctx
 from dataclasses import dataclass, field
 from typing import Optional
@@ -46,6 +45,7 @@ from .compress import decompress_block
 from .footer import ParquetError
 from .format import Encoding, PageType, Type, parse_encoding
 from .iostore import require_full
+from .obs import profiler_span
 from .scanplan import int_stats_span as _int_stats_span, row_group_chunks
 from .jax_decode import (
     DeviceColumnData, ParsedDataPage, _bucket, _bucket_bytes, _bucket_count,
@@ -1109,25 +1109,24 @@ class _Plan:
       traced graph contains — XLA fusions with an HBM-materialized
       intermediate between them (slice → decode → validity is 3; the
       snappy chains add their pointer-doubling rounds; a fused Pallas
-      megakernel is exactly 1).  It rides the completion timer into the
-      registry ``device`` section as ``device_passes``: fused routes
+      megakernel is exactly 1).  It rides the registry ``device``
+      section as ``device_passes``: fused routes
       prove structurally (passes == dispatches) that the round trips are
       gone, where the unfused twins show ≥3 passes per dispatch.
     """
 
-    __slots__ = ("key", "fn", "dyn", "build", "route", "bytes_in",
-                 "bytes_staged", "stages")
+    __slots__ = ("key", "fn", "dyn", "build", "route", "bytes_staged",
+                 "stages")
 
     def __init__(self, key, fn, dyn, build, stages: "int | None" = None):
         self.key = key
         self.fn = fn
         self.dyn = tuple(dyn)
         self.build = build
-        # device-timing attribution (set by _prepare_row_group from the
-        # chunk's ship records): the dominant ship route plus the column's
-        # logical/shipped byte totals — never part of the executable key
+        # dispatch attribution (set by _prepare_row_group): the chunk's
+        # dominant ship route plus the bytes the column staged — never
+        # part of the executable key
         self.route = None
-        self.bytes_in = 0
         self.bytes_staged = 0
         self.stages = (stages if stages is not None
                        else (3 if fn is not None else 0))
@@ -1206,14 +1205,30 @@ def _memo_dev(x):
     return fresh
 
 
+def _program(name: str, fn, scope: "str | None" = None):
+    """``fn`` under the stable program name ``name`` — its XLA module is
+    ``jit_<name>`` — with its ops under the ``tpq.<scope>`` name scope.  A
+    profile then shows the plan's kernel family, not the closure's name."""
+    def program(*args):
+        if scope is None:
+            return fn(*args)
+        with jax.named_scope(f"tpq.{scope}"):
+            return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def _single_for(key, fn):
     """Per-plan jitted runner, cached by the plan's static key (so the
     executable set has exactly the round-4 granularity: one per
-    (kernel-family, bucket) combination, never per row group)."""
+    (kernel-family, bucket) combination, never per row group).  The
+    program is named ``tpq_<family>`` after the key's kernel family."""
     with _FUSED_LOCK:
         hit = _FUSED_CACHE.get(key)
         if hit is None:
-            hit = jax.jit(fn)
+            family = _kernel_family(key)
+            hit = jax.jit(_program(f"tpq_{family}", fn, scope=family))
             _FUSED_CACHE[key] = hit
         return hit
 
@@ -1232,20 +1247,19 @@ def _fused_for(key, fns, arities):
                 i += k
             return tuple(outs)
 
-        jitted = jax.jit(run_all)
+        jitted = jax.jit(_program("tpq_fused_rg", run_all))
         _FUSED_CACHE[key] = jitted
         return jitted
 
 
-def _run_plans(plans, buf_dev, timer: "_DeviceTimer | None" = None):
+def _run_plans(plans, buf_dev, stats: "DeviceStats | None" = None):
     """Execute ``[(name, _Plan)]`` against the staged buffer: pass-throughs
     directly, everything else through per-plan cached jits with
     device-memoized arguments (or one fused call under TPQ_FUSE_RG=1).
 
-    With a ``timer`` (the reader's completion-timing lane), each traced
-    plan's raw jit outputs are handed to the worker with the dispatch
-    timestamp and the plan's ship-route attribution — the per-route device
-    seconds in the registry's ``device`` section."""
+    With ``stats`` (the reader's :class:`DeviceStats`), each program's
+    dispatch is counted on this thread under its ship route and kernel
+    family, with the bytes it returns."""
     out = {}
     traced = []
     for name, p in plans:
@@ -1255,7 +1269,6 @@ def _run_plans(plans, buf_dev, timer: "_DeviceTimer | None" = None):
             traced.append((name, p))
     if not traced:
         return out
-    timing = timer is not None and timer.enabled
     if _FUSE_RG:
         key = tuple(p.key for _, p in traced)
         fused = _fused_for(
@@ -1264,32 +1277,25 @@ def _run_plans(plans, buf_dev, timer: "_DeviceTimer | None" = None):
             tuple(len(p.dyn) for _, p in traced),
         )
         dyn = tuple(_memo_dev(x) for _, p in traced for x in p.dyn)
-        t0 = time.perf_counter() if timing else 0.0
         results = fused(buf_dev, dyn)
-        if timing:
-            # ONE executable ran: one timing entry, attributed to the
-            # dominant (most-bytes-in) plan — per-plan submissions with
-            # the shared t0 would each bank the whole fused wall and sum
-            # to ~N_plans x the real device time
-            dom = max((p for _, p in traced), key=lambda p: p.bytes_in)
-            timer.submit("dispatch", dom.route or ROUTE_PLAIN,
-                         _kernel_family(dom.key), results, t0,
-                         bytes_in=sum(p.bytes_in for _, p in traced),
-                         bytes_staged=sum(p.bytes_staged
-                                          for _, p in traced),
-                         passes=sum(p.stages for _, p in traced))
+        if stats is not None:
+            # ONE program ran: one dispatch of the fused_rg family, under
+            # the route of the plan that shipped the most bytes
+            dom = max((p for _, p in traced), key=lambda p: p.bytes_staged)
+            stats.note_dispatch(dom.route or ROUTE_PLAIN, "fused_rg",
+                                _out_nbytes(results),
+                                sum(p.bytes_staged for _, p in traced),
+                                sum(p.stages for _, p in traced))
         for (name, p), res in zip(traced, results):
             out[name] = p.build(res)
         return out
     for name, p in traced:
         jfn = _single_for(p.key, p.fn)
-        t0 = time.perf_counter() if timing else 0.0
         res = jfn(buf_dev, *(_memo_dev(x) for x in p.dyn))
-        if timing:
-            timer.submit("dispatch", p.route or ROUTE_PLAIN,
-                         _kernel_family(p.key), res, t0,
-                         bytes_in=p.bytes_in, bytes_staged=p.bytes_staged,
-                         passes=p.stages)
+        if stats is not None:
+            stats.note_dispatch(p.route or ROUTE_PLAIN,
+                                _kernel_family(p.key), _out_nbytes(res),
+                                p.bytes_staged, p.stages)
         out[name] = p.build(res)
     return out
 
@@ -1384,8 +1390,8 @@ class _ChunkAssembler:
         # obs.StatsRegistry.ship_feedback puts it next to the measured link
         # lane (TPQ_LINK_MBPS calibration); value-stream records default to
         # the preship plan's cost table, dict-table records pass their own.
-        # The device-lane prediction rides the same record so the measured
-        # per-route completion timing has a model to calibrate against.
+        # The device-lane prediction rides the same record, to set against
+        # a route's measured device time.
         # Fused records additionally carry the UNFUSED chain's modeled
         # device seconds (0.0 elsewhere) — the fusion-win comparison.
         if predicted is None:
@@ -3135,8 +3141,8 @@ class ReaderStats:
     # link lane (staged bytes / stage seconds) for TPQ_LINK_MBPS calibration
     route_pred_seconds: dict = field(default_factory=dict)
     # the model's DEVICE-lane seconds per route (ship.ShipPlanner
-    # .device_costs) — ship_feedback compares them to the measured per-route
-    # completion timing (DeviceStats) for TPQ_DEVICE_MBPS calibration
+    # .device_costs) — ship_feedback compares them to a route's measured
+    # device seconds, where a tree carries them
     route_pred_device_seconds: dict = field(default_factory=dict)
     # for FUSED routes: the unfused chain's modeled device seconds
     # (ship.ShipPlanner.unfused_device_costs) — the prediction the doctor's
@@ -3232,16 +3238,18 @@ class ReaderStats:
 
 
 # ---------------------------------------------------------------------------
-# per-route device timing (the completion-side lane, TPQ_DEVICE_TIMING)
+# per-route / per-kernel-family device dispatch counters
 # ---------------------------------------------------------------------------
 
-# plan-key leading token -> kernel family, the granularity the device lane
-# is attributed at (doctor names "the gather family of the dict route", not
-# an opaque executable hash).  Families follow the decode pipeline's device
-# passes: snappy_resolve (op-table source-map resolves), unpack (bitpack /
-# delta reconstruction), gather (dictionary index gathers), narrow
-# (widen/re-bias of truncated ints), levels (RLE-hybrid level expansion),
-# plain (reshape/bitcast-only decodes and host pass-throughs).
+# plan-key leading token -> kernel family, the granularity device work is
+# attributed at (doctor names "the gather family of the dict route", not
+# an opaque executable hash) and the name each plan program compiles under
+# (``jit_tpq_<family>``, see _single_for).  Families follow the decode
+# pipeline's device passes: snappy_resolve (op-table source-map resolves),
+# unpack (bitpack / delta reconstruction), gather (dictionary index
+# gathers), narrow (widen/re-bias of truncated ints), levels (RLE-hybrid
+# level expansion), plain (reshape/bitcast-only decodes and host
+# pass-throughs).
 _KERNEL_FAMILIES = {
     "snappy": "snappy_resolve", "bytess": "snappy_resolve",
     "narrows": "narrow", "narrow": "narrow",
@@ -3259,297 +3267,107 @@ _KERNEL_FAMILIES = {
 
 
 def _kernel_family(key) -> str:
-    """Kernel family of a plan key (a ``("col", value_key, ...)`` composite
-    classifies by its VALUE plan — levels ride every column)."""
+    """Kernel family of a plan key.  A ``("col", value_key, def_key,
+    rep_key)`` composite classifies by its VALUE plan (levels ride every
+    nullable column), or by its level plan where the values were decoded
+    on the host and only the levels expand on the device."""
     if isinstance(key, tuple) and key:
         if key[0] == "col":
-            return _kernel_family(key[1])
+            inner = key[1]
+            if inner is None:
+                inner = key[2] if key[2] is not None else key[3]
+            return _kernel_family(inner)
         return _KERNEL_FAMILIES.get(key[0], "plain")
     return "plain"
 
 
-def _device_timing_enabled() -> bool:
-    """Whether the completion-timing lane may run: ``TPQ_DEVICE_TIMING``
-    (default on) AND a live jax backend to time against.  A host with no
-    usable device (mis-set JAX_PLATFORMS, driverless box) drops the lane
-    with ONE warning instead of failing every reader construction — the
-    CPU backend counts as a device (block_until_ready is its clock)."""
-    from .obs import env_int, warn_env_once
-
-    if env_int("TPQ_DEVICE_TIMING", 1, lo=0) == 0:
-        return False
-    try:
-        ok = bool(jax.devices())
-    except Exception:  # noqa: BLE001 — no backend is a disable, not a raise
-        ok = False
-    if not ok:
-        warn_env_once("TPQ_DEVICE_TIMING", "<no jax device>",
-                      "disabled (no device clock)")
-        return False
-    return True
-
-
 class DeviceStats:
-    """Per-route / per-kernel-family device completion timing counters.
+    """Per-route / per-kernel-family device dispatch counters.
 
-    The device half of :class:`~tpu_parquet.pipeline.PipelineStats`: where
-    the pipeline's ``dispatch_seconds`` is the HOST wall of issuing async
-    XLA calls (microseconds), these are the seconds until the dispatched
-    work actually COMPLETED on device (``block_until_ready``), keyed by
-    ship route and kernel family — the attribution the plain_int64 gap and
-    the fused-megakernel work need (ROADMAP direction 2).
+    The device half of :class:`~tpu_parquet.pipeline.PipelineStats`,
+    recorded on the dispatching thread at dispatch, with no sync: per ship
+    route and per kernel family, ``dispatches`` (plan programs issued),
+    ``bytes_out`` (the summed ``nbytes`` of the arrays each program
+    returns, known from their shapes without waiting), ``bytes_staged``
+    (link bytes staged for the columns) and ``device_passes`` (the
+    structural pass count, see ``_Plan.stages``).  ``h2d`` counts the
+    staged row-group buffer transfers and their bytes.
 
-    Per route: ``dispatches`` (fused column dispatches timed),
-    ``device_seconds`` (dispatch→completion), ``bytes_in`` (logical output
-    bytes the kernels produce — the planner's per-OUTPUT-byte device charge,
-    so ``bytes_in / device_seconds`` IS the measured ``TPQ_DEVICE_MBPS``),
-    and ``bytes_staged`` (link bytes staged for the route's columns).
-    ``h2d`` times the staged row-group buffer transfers the same way.
-    Thread-safe: the timing worker accumulates while readers snapshot.
-
-    Caveat — completion semantics: the worker serializes each interval
-    against the previous completion (see ``_devtimer_worker``), so the
-    per-route seconds partition ONE device timeline — route shares of
-    the serialized device lane, never a sum that can exceed it.  Per-op
-    exclusive kernel time is ``TPQ_XPROF``'s job, not this lane's.
+    Device TIME is not here: it comes from a profiler capture, where each
+    plan program runs as ``jit_tpq_<family>`` and the pipeline stages as
+    ``tpq.<stage>`` annotations (``obs.profiler_span``).  Thread-safe.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
-        # route -> [dispatches, s, b_in, b_staged, device_passes]
+        # route / family -> [dispatches, bytes_out, bytes_staged, passes]
         self._routes: dict = {}
-        self._kernels: dict = {}  # family -> [dispatches, s]
-        self._h2d = [0, 0.0, 0]   # transfers, seconds, bytes
+        self._kernels: dict = {}
+        self._h2d = [0, 0]  # transfers, bytes
 
-    def note_dispatch(self, route: str, family: str, seconds: float,
-                      bytes_in: int = 0, bytes_staged: int = 0,
-                      passes: int = 1) -> None:
+    def note_dispatch(self, route: str, family: str, bytes_out: int = 0,
+                      bytes_staged: int = 0, passes: int = 1) -> None:
         with self._lock:
-            r = self._routes.setdefault(route, [0, 0.0, 0, 0, 0])
-            r[0] += 1
-            r[1] += seconds
-            r[2] += int(bytes_in)
-            r[3] += int(bytes_staged)
-            r[4] += int(passes)
-            k = self._kernels.setdefault(family, [0, 0.0])
-            k[0] += 1
-            k[1] += seconds
+            for c in (self._routes.setdefault(route, [0, 0, 0, 0]),
+                      self._kernels.setdefault(family, [0, 0, 0, 0])):
+                c[0] += 1
+                c[1] += int(bytes_out)
+                c[2] += int(bytes_staged)
+                c[3] += int(passes)
 
-    def note_h2d(self, seconds: float, nbytes: int = 0) -> None:
+    def note_h2d(self, nbytes: int = 0) -> None:
         with self._lock:
             self._h2d[0] += 1
-            self._h2d[1] += seconds
-            self._h2d[2] += int(nbytes)
+            self._h2d[1] += int(nbytes)
 
     def progress(self) -> dict:
         """Cumulative counters for the sampler's ``device`` track and the
-        watchdog heartbeat (their slope is live device throughput)."""
+        watchdog heartbeat."""
         with self._lock:
             return {
                 "dispatches": sum(r[0] for r in self._routes.values()),
-                "device_seconds": round(
-                    sum(r[1] for r in self._routes.values()), 6),
+                "bytes_out": sum(r[1] for r in self._routes.values()),
                 "h2d_transfers": self._h2d[0],
-                "h2d_seconds": round(self._h2d[1], 6),
+                "h2d_bytes": self._h2d[1],
             }
+
+    @staticmethod
+    def _counters(c) -> dict:
+        return {"dispatches": c[0], "bytes_out": c[1], "bytes_staged": c[2],
+                "device_passes": c[3]}
 
     def as_dict(self) -> dict:
-        # 9 decimals on seconds: a tiny run's sub-µs kernel must not round
-        # to a 0.0 that ship_feedback would read as "unmeasured" (same
-        # contract as ReaderStats.predicted_s)
         with self._lock:
             return {
                 "dispatches": sum(r[0] for r in self._routes.values()),
-                "device_seconds": round(
-                    sum(r[1] for r in self._routes.values()), 9),
-                "routes": {
-                    # device_passes: STRUCTURAL separate-device-pass count
-                    # (see _Plan.stages) — passes == dispatches is the
-                    # registry-level proof a route ran fused (no HBM
-                    # round trips between stages)
-                    route: {"dispatches": r[0],
-                            "device_seconds": round(r[1], 9),
-                            "bytes_in": r[2], "bytes_staged": r[3],
-                            "device_passes": r[4]}
-                    for route, r in sorted(self._routes.items())
-                },
-                "kernels": {
-                    fam: {"dispatches": k[0],
-                          "device_seconds": round(k[1], 9)}
-                    for fam, k in sorted(self._kernels.items())
-                },
-                "h2d": {"transfers": self._h2d[0],
-                        "device_seconds": round(self._h2d[1], 9),
-                        "bytes": self._h2d[2]},
+                "bytes_out": sum(r[1] for r in self._routes.values()),
+                # device_passes: STRUCTURAL separate-device-pass count
+                # (see _Plan.stages) — passes == dispatches is the
+                # registry-level proof a route ran fused (no HBM round
+                # trips between stages)
+                "routes": {route: self._counters(r)
+                           for route, r in sorted(self._routes.items())},
+                "kernels": {fam: self._counters(k)
+                            for fam, k in sorted(self._kernels.items())},
+                "h2d": {"transfers": self._h2d[0], "bytes": self._h2d[1]},
             }
 
 
-class _DeviceTimer:
-    """Completion-side timing worker for the device lane.
-
-    Dispatches (and staged transfers) are ASYNC — blocking the dispatching
-    thread on ``block_until_ready`` would serialize the very pipeline the
-    timing is meant to attribute.  Instead each dispatch hands its output
-    arrays (plus route/family/bytes and its dispatch timestamp) to one
-    daemon worker (``tpq-devtimer``, covered by bench.py's zero-leaked-
-    daemon-threads gate) that blocks until the work completes and folds
-    ``t_complete - t_dispatch`` into :class:`DeviceStats` — and, when a
-    tracer is listening, emits a ``device.<route>`` span so ``pq_tool
-    trace`` prints device lanes in the same p50/p95 table as the host
-    stages.
-
-    Disabled (``TPQ_DEVICE_TIMING=0`` or no backend): ``submit`` is one
-    attribute check, guarded <3% by the tier-1 overhead test.  The worker
-    starts lazily on first submit and ``stop()`` joins it (idempotent;
-    submits after stop are dropped, so a closed reader can never respawn
-    the thread).
-    """
-
-    def __init__(self, stats: DeviceStats, tracer=None,
-                 enabled: "bool | None" = None):
-        self.stats = stats
-        self.tracer = tracer
-        self.enabled = (_device_timing_enabled() if enabled is None
-                        else bool(enabled))
-        self._lock = threading.Lock()
-        self._q = None
-        self._thread = None
-        self._closed = False
-
-    def submit(self, kind: str, route: str, family: str, arrays, t0: float,
-               bytes_in: int = 0, bytes_staged: int = 0,
-               passes: int = 1) -> None:
-        if not self.enabled:
-            return
-        q = self._q
-        if q is None:
-            q = self._start()
-            if q is None:
-                return  # closed
-        q.put((kind, route, family, arrays, t0, bytes_in, bytes_staged,
-               passes))
-
-    def _start(self):
-        import queue
-        import weakref
-
-        with self._lock:
-            if self._closed:
-                return None
-            if self._q is None:
-                self._q = queue.Queue()
-                # the worker references only (queue, stats, tracer) — never
-                # this timer — so an abandoned reader (no close()) lets the
-                # timer become unreachable and the finalizer below delivers
-                # the shutdown sentinel: no thread outlives its reader's
-                # collection, even without the explicit stop()
-                self._thread = threading.Thread(
-                    target=_devtimer_worker,
-                    args=(self._q, self.stats, self.tracer),
-                    name="tpq-devtimer", daemon=True)
-                self._thread.start()
-                weakref.finalize(self, self._q.put, None)
-            return self._q
-
-    def drain(self, timeout: float = 2.0) -> None:
-        """Wait (bounded) until every submitted dispatch has been timed —
-        a mid-session stats read must not observe 1 of a group's 3
-        dispatches just because the worker is still blocking on the other
-        two.  Bounded: a wedged device must not also wedge a flight dump
-        whose registry provider calls this."""
-        import time as _time
-
-        q = self._q
-        if q is None or not self.enabled:
-            return
-        deadline = _time.monotonic() + timeout
-        while q.unfinished_tasks and _time.monotonic() < deadline:
-            _time.sleep(0.002)
-
-    def stop(self) -> None:
-        """Drain and join the worker (idempotent, thread-leak-safe: every
-        already-submitted dispatch is still timed before the join)."""
-        with self._lock:
-            self._closed = True
-            q, t = self._q, self._thread
-            self._q = self._thread = None
-        if t is None:
-            return
-        q.put(None)
-        t.join(timeout=10.0)
-
-
-def _devtimer_worker(q, stats: DeviceStats, tracer) -> None:
-    """The completion worker's loop (module-level on purpose: it must not
-    reference the :class:`_DeviceTimer`, or the timer could never be
-    collected and its shutdown finalizer could never fire).
-
-    Intervals are SERIALIZED against the previous completion: dispatches
-    ride one async device queue, so an interval anchored at its own
-    dispatch time would also contain every earlier dispatch's device time
-    and the per-route sums would overcount the device wall several-fold
-    (K columns back-to-back → ~K/2x).  Anchoring each entry at
-    ``max(own dispatch, previous completion)`` partitions the busy lane:
-    the sums are route shares of one serialized device timeline, directly
-    comparable to the wall-clock host lanes the doctor weighs them
-    against."""
-    import time as _time
-
-    prev_done = 0.0
-    while True:
-        item = q.get()
-        if item is None:
-            return
-        try:
-            kind, route, family, arrays, t0, b_in, b_staged, passes = item
-            try:
-                jax.block_until_ready(arrays)
-            except Exception:  # noqa: BLE001 — a failed dispatch
-                continue       # reports through the consumer
-            t1 = _time.perf_counter()
-            start = max(t0, prev_done)
-            prev_done = t1
-            dt = max(t1 - start, 0.0)
-            if kind == "h2d":
-                stats.note_h2d(dt, b_staged)
-                name = "device.h2d"
-            else:
-                stats.note_dispatch(route, family, dt, b_in, b_staged,
-                                    passes)
-                name = f"device.{route}"
-            if tracer is not None and tracer.active:
-                tracer.complete(name, start, t1, kernel=family,
-                                bytes=int(b_staged or b_in))
-        finally:
-            q.task_done()
+def _out_nbytes(tree) -> int:
+    """Summed ``nbytes`` of a program's output arrays (from their shapes:
+    no sync)."""
+    return sum(int(getattr(x, "nbytes", 0))
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 # ---------------------------------------------------------------------------
 # aligned device profiles (TPQ_XPROF): one bounded-window jax.profiler
-# capture per process whose TraceAnnotations carry the SAME names as the
-# span tracer's stages, so the host Perfetto artifact and the XLA device
-# timeline line up one-to-one
+# capture per process; the pipeline stages annotate it (and any other live
+# capture) through obs.profiler_span
 # ---------------------------------------------------------------------------
 
 _XPROF_LOCK = threading.Lock()
 _XPROF_DONE = False      # one capture per process: xprof dirs are heavy
-_XPROF_ACTIVE = False    # cheap hot-path gate for TraceAnnotations
-
-
-def _xprof_active() -> bool:
-    return _XPROF_ACTIVE
-
-
-def _xprof_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` matching a span-tracer stage name
-    while an xprof window is capturing; a no-op context otherwise (the
-    annotation objects are only built inside a live capture)."""
-    if not _XPROF_ACTIVE:
-        return _noop_ctx()
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling never takes the run down
-        return _noop_ctx()
 
 
 class _XprofWindow:
@@ -3572,7 +3390,7 @@ class _XprofWindow:
         self._started = False
 
     def start(self) -> None:
-        global _XPROF_DONE, _XPROF_ACTIVE
+        global _XPROF_DONE
         if not self.dir:
             return
         with _XPROF_LOCK:
@@ -3585,7 +3403,6 @@ class _XprofWindow:
                 jax.profiler.start_trace(self.dir)
                 self._t0 = _time.perf_counter()
                 self._started = True
-                _XPROF_ACTIVE = True
             except Exception:  # noqa: BLE001
                 self._started = False
 
@@ -3598,12 +3415,10 @@ class _XprofWindow:
             self.stop()
 
     def stop(self) -> None:
-        global _XPROF_ACTIVE
         if not self._started:
             return
         self._started = False
         with _XPROF_LOCK:
-            _XPROF_ACTIVE = False
             try:
                 jax.profiler.stop_trace()
             except Exception:  # noqa: BLE001
@@ -3661,15 +3476,17 @@ class DeviceFileReader:
         # trace file (+ embedded registry) is written at close()
         self._tracer, self._owns_tracer = resolve_tracer(trace)
         validate_crc = resolve_validate(validate_crc)
-        self._host = FileReader(source, columns=columns,
-                                validate_crc=validate_crc,
-                                max_memory=max_memory,
-                                row_filter=row_filter,
-                                trace=self._tracer, store=store,
-                                on_data_error=on_data_error,
-                                quarantine=quarantine,
-                                metadata=metadata, plan=plan,
-                                dict_cache=dict_cache, cancel=cancel)
+        # footer read and parse (and the plan replay or build inside it)
+        with profiler_span("open"):
+            self._host = FileReader(source, columns=columns,
+                                    validate_crc=validate_crc,
+                                    max_memory=max_memory,
+                                    row_filter=row_filter,
+                                    trace=self._tracer, store=store,
+                                    on_data_error=on_data_error,
+                                    quarantine=quarantine,
+                                    metadata=metadata, plan=plan,
+                                    dict_cache=dict_cache, cancel=cancel)
         # the plan IR (scanplan.py): the footer slice + pruning verdicts +
         # ship-route memo this scan consumes.  A caller-supplied plan (the
         # serve.ScanService cache) is REPLAYED — group pruning is adopted
@@ -3737,11 +3554,10 @@ class DeviceFileReader:
         self._stats = ReaderStats()
         self._stats_lock = __import__("threading").Lock()
         self._t0: float | None = None
-        # per-route device completion timing (TPQ_DEVICE_TIMING, default
-        # on): one lazy daemon worker times each staged dispatch to
-        # block_until_ready, keyed by ship route and kernel family
+        # per-route / per-kernel-family dispatch counters, recorded at
+        # dispatch on the calling thread (device time comes from a
+        # profiler capture, not from here)
         self._device_stats = DeviceStats()
-        self._device_timer = _DeviceTimer(self._device_stats, self._tracer)
         # HBM residency ledger: staged buffers register at staging
         # (`_device_staged_pending`), move to `_device_outstanding` at
         # dispatch, and release at finalize — the one point that proves
@@ -3789,12 +3605,8 @@ class DeviceFileReader:
                 # next to the decode lanes they spare
                 self._sampler.add_source("result_cache",
                                          self._result_cache.cache.progress)
-            if self._device_timer.enabled:
-                # the device lane as a curve (slope = live device
-                # throughput); on hosts where the timing lane dropped
-                # (no backend) the track simply never registers
-                self._sampler.add_source("device",
-                                         self._device_stats.progress)
+            # dispatches and their output bytes as a curve
+            self._sampler.add_source("device", self._device_stats.progress)
             self._sampler.start()
         # hang watchdog (obs.Watchdog, TPQ_HANG_S / hang_s=): fires a
         # flight dump (and, policy "raise", aborts the chunk feed's budget
@@ -3854,9 +3666,6 @@ class DeviceFileReader:
 
     def close(self):
         self._watchdog.stop()  # before the sampler: no dump mid-teardown
-        # before the sampler's final tick and the trace write: every
-        # in-flight dispatch must land in the device section first
-        self._device_timer.stop()
         self._xprof.stop()
         # deferred-finalize scans (scan_files) release residency here
         self._release_device_outstanding(all_bytes=True)
@@ -3876,14 +3685,8 @@ class DeviceFileReader:
         reg.add_reader(self._stats)
         reg.add_pipeline(self._pipe_stats)
         reg.note_alloc_peak(self.alloc)
-        if self._device_timer.enabled:
-            # the versioned `device` section (golden-keyed like io/
-            # data_errors); absent entirely when the timing lane dropped,
-            # so consumers see "n/a", never zeros masquerading as
-            # measures.  Drain first: a mid-session read must not miss
-            # dispatches still queued behind the completion worker.
-            self._device_timer.drain()
-            reg.add_device(self._device_stats)
+        # the versioned `device` section (golden-keyed like io/data_errors)
+        reg.add_device(self._device_stats)
         if self._store.stats is not None:
             reg.add_io(self._store.stats)
         if len(self.quarantine.log) or self.quarantine.units_skipped:
@@ -3923,20 +3726,21 @@ class DeviceFileReader:
         from . import scanplan as _sp
 
         plan = self._plan
-        memo_ok = (plan is not None and index is not None
-                   and plan.filter_fp is not None
-                   and plan.filter_fp == _sp.predicate_fingerprint(pred))
-        if memo_ok:
-            hint = plan.pruning_hint(index)
-            if hint is not None:
-                skip, rows_dropped = hint
-                return skip, rows_dropped, {}
-        if f is None:  # the chunk feed passes a thread-safe pread view
-            f = self._host._sr.as_file()  # store-backed, like every read
-        skip, rows_dropped, bufs = _sp.plan_page_pruning(
-            rg, leaves, self.schema, pred, f)
-        if memo_ok:
-            plan.note_pruning(index, skip, rows_dropped)
+        with profiler_span("plan"):
+            memo_ok = (plan is not None and index is not None
+                       and plan.filter_fp is not None
+                       and plan.filter_fp == _sp.predicate_fingerprint(pred))
+            if memo_ok:
+                hint = plan.pruning_hint(index)
+                if hint is not None:
+                    skip, rows_dropped = hint
+                    return skip, rows_dropped, {}
+            if f is None:  # the chunk feed passes a thread-safe pread view
+                f = self._host._sr.as_file()  # store-backed, like every read
+            skip, rows_dropped, bufs = _sp.plan_page_pruning(
+                rg, leaves, self.schema, pred, f)
+            if memo_ok:
+                plan.note_pruning(index, skip, rows_dropped)
         return skip, rows_dropped, bufs
 
     @scoped_x64
@@ -3984,6 +3788,9 @@ class DeviceFileReader:
         f = self._host._sr.as_file()
         self.alloc.reset()
         if collected is None:
+            # the sequential host phase: the pipeline's wall clock starts
+            # with the first group's reads, as its io/decompress lanes do
+            self._pipe_stats.touch_wall()
             skip_pages, rows_dropped, planned_bufs = self._plan_page_pruning(
                 rg, leaves, index=index)
         else:
@@ -4016,20 +3823,23 @@ class DeviceFileReader:
                        "column": ".".join(path), "chunk_offset": offset}
                 buf = planned_bufs.get(path)
                 if buf is None:
-                    f.seek(offset)
-                    buf = f.read(md.total_compressed_size)
+                    with self._pipe_stats.timed("io"):
+                        f.seek(offset)
+                        buf = f.read(md.total_compressed_size)
                 require_full(buf, offset, md.total_compressed_size,
                              context=f"column {'.'.join(path)}")
                 self._stats.chunks += 1
                 self._stats.compressed_bytes += md.total_compressed_size
                 self.alloc.register(md.total_compressed_size)
-                asm = _collect_chunk(
-                    buf, md.codec, md.num_values, leaf, self._deferred,
-                    validate_crc=self.validate_crc, alloc=self.alloc,
-                    statistics=md.statistics,
-                    skip_pages=(skip_pages or {}).get(path),
-                    context=ctx, dict_cache=self._dict_cache,
-                )
+                with self._pipe_stats.timed("decompress"):
+                    asm = _collect_chunk(
+                        buf, md.codec, md.num_values, leaf, self._deferred,
+                        validate_crc=self.validate_crc, alloc=self.alloc,
+                        statistics=md.statistics,
+                        skip_pages=(skip_pages or {}).get(path),
+                        context=ctx, dict_cache=self._dict_cache,
+                    )
+                self._pipe_stats.count_chunk()
                 if asm is not None:
                     # replay the plan IR's memoized route (scanplan.py):
                     # preship starts from the recorded choice instead of
@@ -4054,20 +3864,18 @@ class DeviceFileReader:
                     num_leaf_slots=0,
                 )
                 continue
+            staged0 = stager.total
             plan = asm.finish(stager)
             plans.append((name, plan))
             self._stats.pages_device_expanded += asm.pages_kept_compressed
             tr = self._pipe_stats.tracer
             self._stats.fused_fallbacks += asm.fused_fallbacks
-            logical_sum = shipped_sum = 0
             best_route, best_bytes = None, -1
             for (route, logical, shipped, predicted, predicted_dev,
                  predicted_unfused_dev) in asm.ship_records:
                 self._stats.count_route(route, logical, shipped, predicted,
                                         predicted_dev,
                                         predicted_unfused_dev)
-                logical_sum += logical
-                shipped_sum += shipped
                 if shipped > best_bytes:
                     best_route, best_bytes = route, shipped
                 if tr is not None and tr.active:
@@ -4077,11 +3885,12 @@ class DeviceFileReader:
                                logical=logical, shipped=shipped,
                                predicted_s=round(predicted, 9),
                                predicted_device_s=round(predicted_dev, 9))
-            # device-timing attribution: the column's dispatch is timed
-            # under its dominant (most-shipped-bytes) ship route
+            # dispatch attribution: the column's dispatch is counted
+            # under its dominant (most-shipped-bytes) ship route, with
+            # every byte it registered for staging (value streams,
+            # levels, dictionary and run tables)
             plan.route = best_route or ROUTE_PLAIN
-            plan.bytes_in = logical_sum
-            plan.bytes_staged = shipped_sum
+            plan.bytes_staged = stager.total - staged0
             if self._plan is not None:
                 # memoize the decision into the plan IR: a replay (this
                 # reader's next scan, or the serve cache's next request
@@ -4160,13 +3969,9 @@ class DeviceFileReader:
             for name, col in out.items():
                 rc.put(index, name, col, device_column_nbytes(col))
 
-    def _note_staged(self, stager, buf_dev, t0: float) -> None:
+    def _note_staged(self, stager) -> None:
         """One staged row-group buffer just shipped: account its HBM
-        residency and hand it to the completion timer as an ``h2d``
-        transfer.  ``t0`` must be the POST-stage timestamp — ``stage()``
-        is host-blocking, so an interval anchored before it would contain
-        the whole host staging wall and the ``h2d`` lane would
-        structurally dominate the link lane it is meant to sit next to.
+        residency and count it as an ``h2d`` transfer.
         The bytes land in ``_device_staged_pending`` (not yet dispatched)
         and move to ``_device_outstanding`` at dispatch — finalize proves
         completion only for DISPATCHED groups, and the pipelined path
@@ -4176,8 +3981,7 @@ class DeviceFileReader:
             self.alloc.register_device(n)
             with self._stats_lock:
                 self._device_staged_pending += n
-        self._device_timer.submit("h2d", "h2d", "h2d", buf_dev, t0,
-                                  bytes_staged=n)
+        self._device_stats.note_h2d(n)
 
     def _note_dispatched(self, stager) -> None:
         """The group's staged bytes are now consumed by in-flight kernels:
@@ -4218,17 +4022,15 @@ class DeviceFileReader:
             _tr0 = _time.perf_counter() if _rtrace is not None else 0.0
             if buf_dev is None:
                 t0 = _time.perf_counter()
-                with self._pipe_stats.timed("stage", bytes=stager.total), \
-                        _xprof_annotation("stage"):
+                with self._pipe_stats.timed("stage", bytes=stager.total):
                     buf_dev = stager.stage()
                 t_staged = _time.perf_counter()
                 with self._stats_lock:
                     self._stats.stage_seconds += t_staged - t0
-                self._note_staged(stager, buf_dev, t_staged)
+                self._note_staged(stager)
             t1 = _time.perf_counter()
-            with self._pipe_stats.timed("dispatch"), \
-                    _xprof_annotation("dispatch"):
-                out.update(_run_plans(plans, buf_dev, self._device_timer))
+            with self._pipe_stats.timed("dispatch"):
+                out.update(_run_plans(plans, buf_dev, self._device_stats))
             with self._stats_lock:
                 self._stats.dispatch_seconds += _time.perf_counter() - t1
             self._note_dispatched(stager)
@@ -4278,9 +4080,8 @@ class DeviceFileReader:
     def pipeline_stats(self):
         """Per-stage pipeline timing (io / decompress / stage / dispatch /
         finalize) plus stall time and the in-flight high-water mark — see
-        pipeline.PipelineStats.  The io/decompress stages are only populated
-        when ``prefetch`` > 0 routed the host phase through the chunk pool;
-        stage/dispatch/finalize accumulate on every path."""
+        pipeline.PipelineStats.  Every stage accumulates on both paths: the
+        chunk pool's (``prefetch`` > 0) and the sequential host phase."""
         return self._pipe_stats
 
     @scoped_x64
@@ -4305,8 +4106,7 @@ class DeviceFileReader:
         """Run deferred validity checks (one device sync for all chunks).
         The sync also proves every kernel reading the staged buffers has
         completed, so the HBM residency ledger releases them here."""
-        with self._pipe_stats.timed("finalize"), \
-                _xprof_annotation("finalize"):
+        with self._pipe_stats.timed("finalize"):
             _finalize_many([self])
         self._release_device_outstanding()
         self._pipe_stats.touch_wall()
@@ -4517,15 +4317,12 @@ def _timed_stage(reader: DeviceFileReader, stager: _RowGroupStager):
     import time as _time
 
     t0 = _time.perf_counter()
-    with reader._pipe_stats.timed("stage", bytes=stager.total), \
-            _xprof_annotation("stage"):
+    with reader._pipe_stats.timed("stage", bytes=stager.total):
         buf_dev = stager.stage()
     t_staged = _time.perf_counter()
     with reader._stats_lock:
         reader._stats.stage_seconds += t_staged - t0
-    # post-stage timestamp: the h2d lane times the ASYNC transfer tail,
-    # never the host staging wall the `stage` lane already measured
-    reader._note_staged(stager, buf_dev, t_staged)
+    reader._note_staged(stager)
     return buf_dev
 
 
@@ -5058,7 +4855,11 @@ def scan_files(paths, columns=None, validate_crc=None,
                                           watchdog=watchdog, quarantine=q):
                 yield (pp, out) if with_path else out
                 xprof.tick()
-        _finalize_many(readers)
+        if readers:
+            # the scan's one deferred check, timed into the last file's
+            # pipeline (the merged registry sums every file's)
+            with readers[-1]._pipe_stats.timed("finalize"):
+                _finalize_many(readers)
     finally:
         xprof.stop()
         watchdog.stop()

@@ -62,11 +62,11 @@ def scoped_x64(fn):
 def named_kernel(family):
     """Wrap a kernel so its traced ops carry a ``tpq.<family>`` name scope.
 
-    The names land in the XLA HLO metadata, so a ``TPQ_XPROF`` device
-    profile's op timeline is attributable to the SAME kernel families the
-    completion-timing lane reports (snappy_resolve / unpack / gather /
-    narrow / levels — device_reader._KERNEL_FAMILIES).  Pure trace-time
-    metadata: zero runtime cost in the compiled executable.
+    The names land in the XLA HLO metadata, so a device profile's op
+    timeline is attributable to the SAME kernel families the plan programs
+    are named after (snappy_resolve / unpack / gather / narrow / levels —
+    device_reader._KERNEL_FAMILIES).  Pure trace-time metadata: zero
+    runtime cost in the compiled executable.
     """
 
     def deco(fn):

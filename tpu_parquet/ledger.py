@@ -56,7 +56,7 @@ _ENV_KEYS = (
     "TPQ_LINK_MBPS", "TPQ_FORCE_ROUTE", "TPQ_TRACE", "TPQ_SAMPLE_MS",
     "TPQ_DEVICE_SNAPPY", "TPQ_FUSE_RG", "TPQ_FUSE",
     "TPQ_PALLAS",
-    "TPQ_DEFER_DICT_CHECK", "TPQ_DEVICE_MBPS", "TPQ_DEVICE_TIMING",
+    "TPQ_DEFER_DICT_CHECK", "TPQ_DEVICE_MBPS",
     "TPQ_XPROF", "TPQ_SERVE_CONCURRENCY", "TPQ_SERVE_QUEUE",
     "TPQ_PLAN_CACHE_MB", "TPQ_RESULT_CACHE_MB", "TPQ_RESULT_CACHE_HBM_MB",
     "TPQ_SERVE_BROWNOUT", "TPQ_IO_HEDGE_MS",

@@ -20,6 +20,11 @@ never pull numpy/jax):
   ``DeviceFileReader`` / ``DataLoader`` / ``scan_files`` (per-object tracer,
   written when the object closes).
 
+- :func:`profiler_span` — the same layer boundaries (pipeline stages,
+  serve request spans, open/plan) as ``tpq.<name>`` annotations in any
+  live ``jax.profiler`` capture, on the device events' clock.  jax is
+  looked up through ``sys.modules``, never imported here.
+
 - :class:`LatencyHistogram` — log2-bucketed latency distribution,
   mergeable across threads AND processes (``as_dict``/``from_dict``
   round-trip), giving per-stage p50/p95 where the round-6 counters only
@@ -85,7 +90,7 @@ __all__ = [
     "env_float", "env_int", "fleet_host",
     "flight_dump_path",
     "flight_recorder", "install_flight_hooks", "note_worker_crash",
-    "register_flight_registry", "register_flight_source",
+    "profiler_span", "register_flight_registry", "register_flight_source",
     "render_openmetrics",
     "resolve_hang_s", "resolve_sample_ms", "resolve_tracer",
     "set_request_trace", "trace_summary", "warn_env_once",
@@ -929,6 +934,34 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# jax.profiler.TraceAnnotation, found once jax is loaded (this module never
+# imports jax itself)
+_ANNOTATION = None
+
+
+def profiler_span(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``tpq.<name>`` while a
+    profiler capture is live, whoever started it; the shared no-op
+    otherwise, or where jax is not loaded.
+
+    An annotation lands on the same clock as the device events, so a trace
+    reduction can put each idle gap down to the program stage around it.
+    Keep ``name`` bare (no ids folded in): ``args`` ride the event as
+    stats and the name stays what the breakdown sums by.  With no capture
+    live the cost is one C++ flag check (tests/test_profiler_spans.py
+    measures it)."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                      None)
+        if ann is None:
+            return _NULL_SPAN
+        _ANNOTATION = ann
+    if not ann.is_enabled():
+        return _NULL_SPAN
+    return ann("tpq." + name, **args)
+
 
 class _Span:
     __slots__ = ("_tr", "_name", "_args", "_t0")
@@ -1719,10 +1752,10 @@ class StatsRegistry:
 
     def add_device(self, devstats) -> None:
         """Fold a :class:`~tpu_parquet.device_reader.DeviceStats` in (the
-        ``device`` section: per-route and per-kernel-family completion-side
-        dispatch timing plus the h2d transfer lane — all flows, so
-        multi-file scans compose by addition).  Raw dicts accepted for
-        tests and cross-process merges."""
+        ``device`` section: per-route and per-kernel-family dispatch
+        counters plus the staged transfers — all flows, so multi-file
+        scans compose by addition).  Raw dicts accepted for tests and
+        cross-process merges."""
         d = devstats if isinstance(devstats, dict) else devstats.as_dict()
         with self._lock:
             if self._device is None:
@@ -1868,10 +1901,10 @@ class StatsRegistry:
 
         The DEVICE lane rides each route the same way: predicted device
         seconds from the planner's device cost term
-        (``predicted_device_s`` on ReaderStats), measured from the
-        completion-side device timing (the ``device`` section's per-route
-        ``device_seconds``, ``TPQ_DEVICE_TIMING``) — null when the timing
-        lane never ran, same contract as the link lane.
+        (``predicted_device_s`` on ReaderStats), measured from a ``device``
+        section's per-route ``device_seconds`` where an older tree carries
+        them — null otherwise, same contract as the link lane (device time
+        per kernel family now comes from a profiler capture).
         """
         with self._lock:
             reader = dict(self._reader or {})
@@ -1893,7 +1926,8 @@ class StatsRegistry:
             dev_pred = float(c.get("predicted_device_s", 0.0) or 0.0)
             dr = dev_routes.get(route) or {}
             dev_meas = (float(dr["device_seconds"])
-                        if dr.get("dispatches") else None)
+                        if dr.get("dispatches") and "device_seconds" in dr
+                        else None)
             # fused routes carry the UNFUSED chain's device prediction too
             # (null elsewhere) — the fusion-win comparison's bar
             unf = float(c.get("predicted_unfused_device_s", 0.0) or 0.0)
@@ -2271,10 +2305,9 @@ def trace_summary(doc) -> dict:
         r["measured_seconds"] = round(meas, 9) if meas is not None else None
         r["error_ratio"] = (round(meas / pred, 3)
                             if meas is not None and pred else None)
-        # the device lane: completion-side `device.<route>` spans (the
-        # TPQ_DEVICE_TIMING worker emits one per dispatch).  Same null
-        # contract — a run with the timing lane off reports null, and an
-        # artifact predating it can never KeyError.
+        # the device lane: `device.<route>` spans, which only older trace
+        # artifacts carry (device time now comes from a profiler
+        # capture).  Same null contract: null where no span was written.
         dev_pred = r["device_predicted_seconds"]
         dev = spans.get(f"device.{name}")
         dev_meas = sum(dev) if dev else None
@@ -2425,13 +2458,12 @@ def doctor_registry(tree: dict) -> "dict | None":
     - ``host_decompress``  ``io + decompress + recompress`` seconds (the
       host's half of the work; falls back to the reader's ``host_seconds``
       for prefetch=0 runs that never routed through the chunk pool)
-    - ``device_resolve``  the measured per-route device completion seconds
-      (the ``device`` registry section, ``TPQ_DEVICE_TIMING``); falls back
-      to ``dispatch + finalize`` host-side seconds for artifacts predating
-      the device section (never a KeyError — old records stay readable)
-    - ``h2d``             measured h2d transfer completion seconds (the
-      ``device`` section's ``h2d`` lane; 0 for old artifacts, so the new
-      verdict can never fire on a record that carries no evidence for it)
+    - ``device_resolve``  the per-route device seconds an older tree's
+      ``device`` section carries; otherwise the ``dispatch + finalize``
+      host-side seconds (the registry's device section now holds counters
+      only: device time per kernel family comes from a profiler capture)
+    - ``h2d``             an older tree's h2d transfer seconds (0 where the
+      tree carries none, so the verdict never fires without evidence)
     - ``stall``           budget backpressure (the submitter blocked on
       ``max_memory`` — more memory or less lookahead, not more bandwidth)
 
@@ -2440,7 +2472,8 @@ def doctor_registry(tree: dict) -> "dict | None":
     ``DOCTOR_ERROR_BAND``, the report carries ``recalibrate_link_mbps`` —
     the measured staging rate as the ``TPQ_LINK_MBPS`` value to re-run
     with (exactly the 1B re-measure procedure in ROADMAP item 1).  With a
-    ``device`` section, the report additionally carries a ``device`` block
+    timed ``device`` section (an older tree), the report additionally
+    carries a ``device`` block
     naming the dominant device route and kernel family with its
     predicted-vs-measured error ratio, and ``recalibrate_device_mbps``
     when that ratio leaves the band — the device twin of the link loop.
@@ -2466,15 +2499,16 @@ def doctor_registry(tree: dict) -> "dict | None":
             + g(pipe, "recompress_seconds"))
     if host == 0.0:
         host = g(reader, "host_seconds")
+    # routes with device seconds: only trees written before device time
+    # moved to the profiler carry them
     dev_routes = {r: c for r, c in (dev.get("routes") or {}).items()
-                  if isinstance(c, dict)}
+                  if isinstance(c, dict) and "device_seconds" in c}
     dev_resolve = sum(g(c, "device_seconds") for c in dev_routes.values())
     lanes = {
         "link": g(pipe, "stage_seconds"),
         "host_decompress": host,
-        # measured completion seconds when the timing lane ran; the
-        # host-side dispatch+finalize wall otherwise (old artifacts,
-        # TPQ_DEVICE_TIMING=0 runs)
+        # an older tree's device seconds; the host-side dispatch+finalize
+        # wall otherwise
         "device_resolve": dev_resolve or (g(pipe, "dispatch_seconds")
                                           + g(pipe, "finalize_seconds")),
         "h2d": g(dev.get("h2d") or {}, "device_seconds"),

@@ -50,7 +50,7 @@ from typing import Callable, Iterable, Iterator, Optional, TypeVar
 
 from .alloc import InFlightBudget
 from .obs import (LatencyHistogram, current_tracer, note_worker_crash,
-                  register_flight_source)
+                  profiler_span, register_flight_source)
 
 T = TypeVar("T")
 R = TypeVar("R")
@@ -88,7 +88,9 @@ class PipelineStats:
     :class:`~tpu_parquet.obs.LatencyHistogram` (p50/p95 where the sums
     alone can't attribute a stall — see obs.StatsRegistry), and ``timed``
     emits a span on ``tracer`` (the ``TPQ_TRACE`` process tracer by
-    default; a disabled tracer costs one ``if``).
+    default; a disabled tracer costs one ``if``) and a ``tpq.<stage>``
+    annotation into any live profiler capture (``obs.profiler_span``);
+    so does the budget wait in :func:`prefetch_map` (``tpq.stall``).
     """
 
     def __init__(self, prefetch: int = 0, budget_bytes: int = 0,
@@ -129,7 +131,8 @@ class PipelineStats:
     def timed(self, stage: str, **span_args):
         t0 = time.perf_counter()
         try:
-            yield
+            with profiler_span(stage):
+                yield
         finally:
             t1 = time.perf_counter()
             self.add(stage, t1 - t0)
@@ -424,7 +427,8 @@ def prefetch_map(
                     if not block_ok or pending:
                         return False  # drain the head; its release frees room
                     t0 = time.perf_counter()
-                    budget.acquire(c, cancel=cancel)
+                    with profiler_span("stall"):
+                        budget.acquire(c, cancel=cancel)
                     if stats is not None:
                         stats.add_stall(time.perf_counter() - t0, t0)
                 if stats is not None:

@@ -24,6 +24,7 @@ from .footer import ParquetError, read_file_metadata
 from .format import FileMetaData, Type
 from .iostore import CoalescedFetcher, require_full, resolve_store
 from .iostore_async import engine_for_store
+from .obs import profiler_span
 from .pipeline import PipelineStats, SharedReader, prefetch_map
 from .schema.core import Schema, SchemaNode
 
@@ -157,32 +158,34 @@ class FileReader:
             self._result_cache = result_cache
             from .scanplan import build_scan_plan, predicate_fingerprint
 
-            fp = predicate_fingerprint(row_filter)
-            cols_sig = tuple(sorted(
-                ".".join(l.path) for l in self.schema.selected_leaves()))
-            fp_match = ((row_filter is None and plan is not None
-                         and plan.filter_fp is None)
-                        or (fp is not None and plan is not None
-                            and plan.filter_fp == fp))
-            if plan is not None and fp_match and plan.columns == cols_sig:
-                # replay a cached ScanPlan (scanplan.py): the group-pruning
-                # verdict is adopted, never recomputed; a plan whose
-                # projection or filter doesn't match falls through to a
-                # fresh build rather than a wrong replay
-                self._plan = plan
-                self._rg_keep = (list(plan.rg_keep)
-                                 if plan.rg_keep is not None else None)
-            else:
-                if row_filter is not None:
-                    from .predicate import prune_row_groups
-
-                    self._rg_keep = prune_row_groups(
-                        self.metadata, self.schema, row_filter)
+            # the ScanPlan replay or build (tpq.plan in a profiler capture)
+            with profiler_span("plan"):
+                fp = predicate_fingerprint(row_filter)
+                cols_sig = tuple(sorted(
+                    ".".join(l.path) for l in self.schema.selected_leaves()))
+                fp_match = ((row_filter is None and plan is not None
+                             and plan.filter_fp is None)
+                            or (fp is not None and plan is not None
+                                and plan.filter_fp == fp))
+                if plan is not None and fp_match and plan.columns == cols_sig:
+                    # replay a cached ScanPlan (scanplan.py): the group-pruning
+                    # verdict is adopted, never recomputed; a plan whose
+                    # projection or filter doesn't match falls through to a
+                    # fresh build rather than a wrong replay
+                    self._plan = plan
+                    self._rg_keep = (list(plan.rg_keep)
+                                     if plan.rg_keep is not None else None)
                 else:
-                    self._rg_keep = None
-                self._plan = build_scan_plan(
-                    self.metadata, self.schema, row_filter=row_filter,
-                    filter_fp=fp, rg_keep=self._rg_keep)
+                    if row_filter is not None:
+                        from .predicate import prune_row_groups
+
+                        self._rg_keep = prune_row_groups(
+                            self.metadata, self.schema, row_filter)
+                    else:
+                        self._rg_keep = None
+                    self._plan = build_scan_plan(
+                        self.metadata, self.schema, row_filter=row_filter,
+                        filter_fp=fp, rg_keep=self._rg_keep)
         except BaseException:
             # a constructor failure (bad footer, bad projection, bad filter)
             # must not leak the fd this reader opened

@@ -36,7 +36,7 @@ from collections import OrderedDict
 
 from ..footer import read_file_metadata
 from ..iostore import ByteStore
-from ..obs import current_request_trace, env_int
+from ..obs import current_request_trace, env_int, profiler_span
 
 __all__ = ["PlanCache", "BoundDictCache", "CacheStats"]
 
@@ -270,11 +270,12 @@ class PlanCache:
             return (meta, Schema.from_file_metadata(meta)), nbytes + 4096
 
         key = self.file_key(source, store)
-        if key is None:
-            with self._lock:
-                self.stats.misses["footer"] += 1
-            return build()[0]
-        return self._read_through("footer", (key,), build)
+        with profiler_span("open"):
+            if key is None:
+                with self._lock:
+                    self.stats.misses["footer"] += 1
+                return build()[0]
+            return self._read_through("footer", (key,), build)
 
     # -- plans -----------------------------------------------------------------
 
@@ -300,11 +301,12 @@ class PlanCache:
             return plan, plan.nbytes()
 
         cacheable = key is not None and (row_filter is None or fp is not None)
-        if not cacheable:
-            with self._lock:
-                self.stats.misses["plan"] += 1
-            return build()[0]
-        return self._read_through("plan", (key, cols_sig, fp), build)
+        with profiler_span("plan"):
+            if not cacheable:
+                with self._lock:
+                    self.stats.misses["plan"] += 1
+                return build()[0]
+            return self._read_through("plan", (key, cols_sig, fp), build)
 
     # -- decoded dictionaries --------------------------------------------------
     # Folded into the tiered ResultCache (one LRU, one byte budget with the

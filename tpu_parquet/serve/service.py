@@ -40,15 +40,15 @@ import threading
 import time
 import weakref
 from collections.abc import Mapping
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 
 from ..alloc import InFlightBudget
 from ..errors import (CancelledError, DeadlineExceededError, HangError,
                       OverloadError, ParquetError, RetryExhaustedError,
                       TransientIOError)
 from ..obs import (LatencyHistogram, MetricsDumper, RequestTrace,
-                   TailSampler, env_float, env_int, register_flight_source,
-                   resolve_hang_s, set_request_trace)
+                   TailSampler, env_float, env_int, profiler_span,
+                   register_flight_source, resolve_hang_s, set_request_trace)
 from ..resilience import BreakerBoard, CancelToken
 from .cache import BoundDictCache, PlanCache
 from .stream import (StreamingScan, check_cursor_compatible, request_digest,
@@ -74,10 +74,33 @@ _CLASSIFIED_FAILURES = (RetryExhaustedError, TransientIOError, ParquetError,
 _req_ids = itertools.count(1)
 
 
+@contextmanager
 def _span(trace, name, **args):
-    """A RequestTrace span, or a no-op when the request carries no trace
-    (tracing off) — keeps the instrumented call sites branch-free."""
-    return trace.span(name, **args) if trace is not None else nullcontext()
+    """A RequestTrace span (none when the request carries no trace) and a
+    ``tpq.<name>`` annotation in any live profiler capture — keeps the
+    instrumented call sites branch-free."""
+    with profiler_span(name), (trace.span(name, **args)
+                               if trace is not None else nullcontext()):
+        yield
+
+
+def _wait_on_device(cols: dict) -> None:
+    """Block until every device array of one file's decoded columns
+    (``{column: [DeviceColumnData]}``) is ready.
+
+    A worker holds its slot until its file's device work is done, so the
+    service's concurrency bounds the device work in flight: concurrent
+    requests then share the device file by file, rather than a short
+    request queueing on the device behind every file a long one has
+    already dispatched, and ``exec_seconds`` includes device time."""
+    import dataclasses
+
+    import jax
+
+    jax.block_until_ready([
+        v for parts in cols.values() for cd in parts
+        for v in (getattr(cd, f.name) for f in dataclasses.fields(cd))
+        if isinstance(v, jax.Array)])
 
 
 def _count_rows(result: dict) -> int:
@@ -600,47 +623,51 @@ class ScanService:
                 self._inflight[ticket.id] = (str(first), t_start)
             rows = 0
             yielded = False
-            try:
-                # a request that expired (or was cancelled) while queued
-                # fails HERE, typed, before any byte is charged or read
-                ticket.token.check()
-                if session is not None:
-                    # the session IS the response: the caller's result()
-                    # unblocks with it now, batches flow as they decode.
-                    # Under stream-aware fair scheduling the session hands
-                    # this slot back between batches whenever another
-                    # tenant has queued work; otherwise it occupies the
-                    # slot until it drains, errors, or is cancelled.
-                    if not ticket.done():
-                        ticket._finish(result=session)
-                    ycheck = None
-                    if self._stream_yield and tenant is not None:
-                        tname = tenant.name
-                        ycheck = (lambda _t=tname:
-                                  self._q.has_other_waiters(_t))
-                    finished = session._produce(yield_check=ycheck)
-                    if not finished and self._closed:
-                        # closed while mid-yield: requeueing would strand
-                        # the session behind the shutdown sentinels
-                        exc0 = CancelledError(
-                            "scan service closed; streaming session "
-                            "terminated")
-                        session._abort(exc0)
-                        raise exc0
-                    yielded = not finished
-                    rows = session.rows_emitted
-                    result, exc = session, None
-                else:
-                    result, exc = self._execute(request, ticket.token), None
-                    rows = _count_rows(result)
-            except BaseException as e:  # noqa: BLE001 — delivered to caller
-                result, exc = None, e
-                yielded = False
-                # a continuation leg's ticket already resolved to the
-                # session — its failure must reach the consumer through
-                # the session buffer (first verdict wins; idempotent)
-                if session is not None and ticket.done():
-                    session._fail(e)
+            # one request's execution: tpq.serve.request in a profiler
+            # capture, around its cache probes, admission and reads
+            with profiler_span("serve.request", id=ticket.id):
+                try:
+                    # a request that expired (or was cancelled) while queued
+                    # fails HERE, typed, before any byte is charged or read
+                    ticket.token.check()
+                    if session is not None:
+                        # the session IS the response: the caller's result()
+                        # unblocks with it now, batches flow as they decode.
+                        # Under stream-aware fair scheduling the session hands
+                        # this slot back between batches whenever another
+                        # tenant has queued work; otherwise it occupies the
+                        # slot until it drains, errors, or is cancelled.
+                        if not ticket.done():
+                            ticket._finish(result=session)
+                        ycheck = None
+                        if self._stream_yield and tenant is not None:
+                            tname = tenant.name
+                            ycheck = (lambda _t=tname:
+                                      self._q.has_other_waiters(_t))
+                        finished = session._produce(yield_check=ycheck)
+                        if not finished and self._closed:
+                            # closed while mid-yield: requeueing would strand
+                            # the session behind the shutdown sentinels
+                            exc0 = CancelledError(
+                                "scan service closed; streaming session "
+                                "terminated")
+                            session._abort(exc0)
+                            raise exc0
+                        yielded = not finished
+                        rows = session.rows_emitted
+                        result, exc = session, None
+                    else:
+                        result = self._execute(request, ticket.token)
+                        exc = None
+                        rows = _count_rows(result)
+                except BaseException as e:  # noqa: BLE001 — to the caller
+                    result, exc = None, e
+                    yielded = False
+                    # a continuation leg's ticket already resolved to the
+                    # session — its failure must reach the consumer through
+                    # the session buffer (first verdict wins; idempotent)
+                    if session is not None and ticket.done():
+                        session._fail(e)
             if yielded:
                 # mid-stream slot yield: book this leg's seconds, hand the
                 # slot back, requeue the session as a fresh arrival (DRR
@@ -847,6 +874,7 @@ class ScanService:
                                     for name, cd in group.items():
                                         cols.setdefault(name,
                                                         []).append(cd)
+                                _wait_on_device(cols)
                                 out[str(path)] = {
                                     name: (parts[0] if len(parts) == 1
                                            else parts)

@@ -98,8 +98,8 @@ DEVICE_RESOLVE_MBPS = {
     # the tier-1 tests' planning point
     "cpu": 3000.0,
     # TPU v5e: device_snappy resolved SF1 lineitem's two PLAIN 8-byte
-    # columns, 48,000,000 output bytes, in 7.025 device s (TPQ_DEVICE_TIMING
-    # registry, one chip, PR 21).  The narrow widen and fused_plain ran at
+    # columns, 48,000,000 output bytes, in 7.025 device s (a host thread's
+    # completion wait, one chip, PR 21).  The narrow widen and fused_plain ran at
     # ~3 GB/s there, so this rate overprices them; they lose to plain at
     # the v5e link rate regardless (warm SF1: plain x12 2.546 s, narrow x6
     # + plain x6 2.752 s, same run)
@@ -347,9 +347,9 @@ class ShipPlanner:
         :meth:`plan` call just returned — to skip re-running the
         feasibility walk).
 
-        The device lane is what the per-route completion timing
-        (``TPQ_DEVICE_TIMING``, device_reader) measures: kernel time from
-        dispatch to ``block_until_ready``.  ``plain`` models ~0 (reshape +
+        The device lane is a route's kernel time on the device (a
+        profiler capture's ``jit_tpq_<family>`` programs).  ``plain``
+        models ~0 (reshape +
         bitcast, no compute); the compressed routes charge the op-table
         resolve per OUTPUT byte at ``device_mbps``; ``narrow`` charges the
         widen/re-bias pass the same way.  These ride ReaderStats per route
