@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_parquet import device_reader as dr
+from tpu_parquet import jax_decode as jd
 from tpu_parquet import pallas_kernels as pk
 from tpu_parquet.jax_kernels import enable_x64
 
@@ -110,6 +111,34 @@ def _delta(bits, s):
                  max_width=bits, total=ROWS, n_pages=64, m_max=512), True)
 
 
+def _hybrid(width, rp, s):
+    # one stream width: definition levels, uniform dictionary indices
+    return (jd._hybrid_jit,
+            (_sds((BUF,), jnp.uint8, s), _sds((rp,), jnp.int64, s),
+             _sds((rp,), jnp.bool_, s), _sds((rp,), jnp.uint32, s),
+             _sds((rp,), jnp.int64, s), _sds((), jnp.int64, s)),
+            dict(width=width, count=ROWS), True)
+
+
+def _hybrid_vw(max_width, rp, s):
+    # dictionary indices whose width grows page to page (pyarrow's pages)
+    return (jd._hybrid_vw_jit,
+            (_sds((BUF,), jnp.uint8, s), _sds((rp,), jnp.int64, s),
+             _sds((rp,), jnp.bool_, s), _sds((rp,), jnp.uint32, s),
+             _sds((rp,), jnp.int64, s), _sds((rp,), jnp.uint32, s),
+             _sds((), jnp.int64, s)),
+            dict(max_width=max_width, count=ROWS), True)
+
+
+def _combine(rp, s):
+    # the Pallas route's run-table combine over the unpacked groups
+    return (dr._hybrid_combine_staged_jit,
+            (_sds((8 * pk.bp_groups_pad(GROUPS),), jnp.uint32, s),
+             _sds((BUF,), jnp.uint8, s), _sds((), jnp.int64, s),
+             _sds((), jnp.int32, s)),
+            dict(count=ROWS, rp=rp), True)
+
+
 CASES = {
     "unpack_w1": functools.partial(_unpack, 1),
     "unpack_w13": functools.partial(_unpack, 13),
@@ -124,6 +153,10 @@ CASES = {
     "snappy_plain_float64": functools.partial(_snappy_plain, "float64"),
     "delta_int64": functools.partial(_delta, 64),
     "delta_int32": functools.partial(_delta, 32),
+    "hybrid_w1_rp16": functools.partial(_hybrid, 1, 16),
+    "hybrid_vw16_rp1024": functools.partial(_hybrid_vw, 16, 1024),
+    "hybrid_vw24_rp16384": functools.partial(_hybrid_vw, 24, 16384),
+    "combine_rp1024": functools.partial(_combine, 1024),
 }
 
 

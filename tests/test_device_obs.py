@@ -68,7 +68,8 @@ def _device_stats():
 
 def test_device_stats_as_dict_golden_keys():
     d = _device_stats().as_dict()
-    assert set(d) == {"dispatches", "bytes_out", "routes", "kernels", "h2d"}
+    assert set(d) == {"dispatches", "bytes_out", "routes", "kernels", "h2d",
+                      "run_expand"}
     assert d["dispatches"] == 2
     assert d["bytes_out"] == 5000
     assert set(d["routes"]) == {"plain", "device_snappy"}
@@ -81,7 +82,26 @@ def test_device_stats_as_dict_golden_keys():
     assert d["kernels"]["snappy_resolve"]["bytes_staged"] == 1500
     assert set(d["h2d"]) == {"transfers", "bytes"}
     assert d["h2d"]["bytes"] == 2500
+    assert d["run_expand"] == {"expansions": 0, "runs": 0, "lanes": 0}
     json.dumps(d)  # artifact-ready
+
+
+def test_device_stats_run_expand_counts_and_merges():
+    """``run_expand`` sums what each dispatched program expands — hybrid
+    expansions, their real run entries, their lanes — and merges across
+    registries like every other flow."""
+    ds = _device_stats()
+    ds.note_dispatch("plain", "gather", bytes_out=800, run_expand=(2, 70,
+                                                                     4096))
+    ds.note_dispatch("plain", "levels", bytes_out=64, run_expand=(1, 3, 16))
+    assert ds.as_dict()["run_expand"] == {"expansions": 3, "runs": 73,
+                                          "lanes": 4112}
+    a, b = StatsRegistry(), StatsRegistry()
+    a.add_device(ds)
+    b.add_device(ds)
+    a.merge_dict(b.as_dict())
+    assert a.as_dict()["device"]["run_expand"] == {
+        "expansions": 6, "runs": 146, "lanes": 8224}
 
 
 def test_registry_device_section_merge_from_and_dict():
@@ -591,3 +611,49 @@ def test_xprof_window_covers_scan_files(tmp_path, monkeypatch):
     assert not jax.profiler.TraceAnnotation.is_enabled()
     files = [f for _, _, fs in os.walk(xdir) for f in fs]
     assert files, "scan_files wrote no xprof artifact"
+
+
+def test_scan_records_run_expand(tmp_path, monkeypatch):
+    """A scan of a multi-page dictionary file counts every hybrid
+    expansion it dispatches under ``device.run_expand``: one per
+    dictionary chunk and one per definition-level stream, with the real
+    run entries of their pages and their bucketed lanes.  The Pallas route
+    (interpreted here) counts the same as the XLA route."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from tpu_parquet.device_reader import DeviceFileReader
+
+    rng = np.random.default_rng(11)
+    n = 24_000
+    # repeated stretches and scattered keys: RLE and bit-packed index runs
+    k = np.repeat(rng.integers(0, 2000, n // 8), 8)
+    scattered = rng.random(n) < 0.4
+    k[scattered] = rng.integers(0, 2000, int(scattered.sum()))
+    s = np.array([f"v{i % 97}" for i in rng.integers(0, 1000, n)], object)
+    nulls = rng.random(n) < 0.2
+    s[nulls] = None
+    table = pa.table({"k": pa.array(k, pa.int64()), "s": pa.array(s)},
+                     schema=pa.schema([pa.field("k", pa.int64(), False),
+                                       pa.field("s", pa.string())]))
+    path = str(tmp_path / "dict_pages.parquet")
+    pq.write_table(table, path, row_group_size=n // 2, data_page_size=2048,
+                   use_dictionary=True, compression="snappy")
+    meta = pq.ParquetFile(path).metadata
+    assert meta.num_row_groups == 2
+    assert all(meta.row_group(g).column(c).total_compressed_size > 4096
+               for g in range(2) for c in range(2))  # several pages each
+
+    counts = {}
+    for mode in ("0", "1"):
+        monkeypatch.setenv("TPQ_PALLAS", mode)
+        with DeviceFileReader(path) as r:
+            for _ in r.iter_row_groups():
+                pass
+            counts[mode] = r.obs_registry().as_dict()["device"]["run_expand"]
+    got = counts["0"]
+    # 2 row groups x (k's indices, s's indices, s's definition levels)
+    assert got["expansions"] == 6
+    assert got["lanes"] >= 3 * n - int(nulls.sum())  # s indexes non-nulls
+    assert 6 * 2 < got["runs"] < got["lanes"]  # many pages, many runs
+    assert counts["1"] == got

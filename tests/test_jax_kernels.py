@@ -6,9 +6,13 @@ results.  Runs on the virtual 8-device CPU mesh from conftest.py — the same XL
 programs compile for TPU unchanged.
 """
 
+import re
+import zlib
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from tpu_parquet import jax_decode as jd
@@ -332,3 +336,233 @@ def test_hybrid_meta_width0_huge_groups_parity():
         pytest.skip("native library unavailable")
     assert a.n_runs == b.n_runs and a.consumed == b.consumed
     np.testing.assert_array_equal(a.run_ends, b.run_ends)
+
+
+# ---------------------------------------------------------------------------
+# run-table expansion: the reader's expansion sites against the host decoder
+# ---------------------------------------------------------------------------
+
+def _stretches(rng, n, width, rle_share=0.5, lo=8, hi=200):
+    """``n`` values of ``width`` bits in stretches: constant ones (which the
+    encoder writes as RLE runs) and random ones (bit-packed runs)."""
+    top = 1 << width
+    parts, total = [], 0
+    while total < n:
+        k = int(rng.integers(lo, hi + 1))
+        if rng.random() < rle_share:
+            parts.append(np.full(k, rng.integers(0, top), dtype=np.uint64))
+        else:
+            parts.append(rng.integers(0, top, k, dtype=np.uint64))
+        total += k
+    return np.concatenate(parts)[:n]
+
+
+def _encode_pages(rng, widths, sizes, **kw):
+    """[(encoded, width, n)] with per-page widths, as a dictionary chunk's
+    pages carry them (the index width grows as the dictionary fills)."""
+    enc_kw = {k: kw.pop(k) for k in ("use_rle_runs",) if k in kw}
+    pages = []
+    for w, n in zip(widths, sizes):
+        vals = _stretches(rng, n, w, **kw)
+        pages.append((rle.encode(vals, w, **enc_kw), w, n))
+    return pages
+
+
+def _chunk_tables(pages):
+    """Stage ``pages`` back to back and merge their run tables into one
+    chunk-global table, as the reader's dictionary finish does.  Returns
+    (padded buffer, [ends, is_rle, values, starts, widths], real count,
+    real run count, host-decoded values)."""
+    from tpu_parquet.device_reader import _merge_run_tables
+
+    ends_l, rle_l, vals_l, starts_l, widths_l = [], [], [], [], []
+    blobs, host = [], []
+    base = pos0 = 0
+    for enc, w, n in pages:
+        meta = jd.parse_hybrid_meta(enc, w, n)
+        k = meta.n_runs
+        ends_l.append(meta.run_ends[:k] + pos0)
+        rle_l.append(meta.run_is_rle[:k])
+        vals_l.append(meta.run_values[:k])
+        starts_l.append(meta.run_bit_starts[:k] + base * 8 - pos0 * w)
+        widths_l.append(np.full(k, w, dtype=np.uint32))
+        host.append(rle.decode(enc, w, n).astype(np.uint32))
+        blobs.append(enc)
+        base += len(enc)
+        pos0 += n
+    tables = list(_merge_run_tables(ends_l, rle_l, vals_l, starts_l,
+                                    fill_end=pos0, widths_l=widths_l))
+    n_runs = sum(len(e) for e in ends_l)
+    return (jd.pad_buffer(b"".join(blobs)), tables, pos0, n_runs,
+            np.concatenate(host))
+
+
+def _with_zero_length_runs(tables, rng, n_zero=40):
+    """Insert runs of length zero (garbage fields, ends equal to the run
+    before them, at the table's head too): no lane may take their fields."""
+    ends = tables[0]
+    at = np.sort(rng.integers(0, len(ends), n_zero))
+    at[0] = 0
+    prev = np.where(at > 0, ends[np.maximum(at - 1, 0)], 0)
+    junk = [prev, rng.random(n_zero) < 0.5,
+            rng.integers(0, 1 << 32, n_zero, dtype=np.uint64),
+            rng.integers(-(1 << 20), 1 << 20, n_zero),
+            rng.integers(1, 33, n_zero)]
+    return [np.insert(t, at, j.astype(t.dtype)) for t, j in zip(tables, junk)]
+
+
+def _expand(site, buf, tables, total, count, max_width):
+    ends, isr, vals, starts, widths = tables
+    if site == "hyb":
+        return jd._hybrid_jit(buf, ends, isr, vals, starts, np.int64(total),
+                              width=max_width, count=count)
+    return jd._hybrid_vw_jit(buf, ends, isr, vals, starts, widths,
+                             np.int64(total), max_width=max_width,
+                             count=count)
+
+
+# (page widths, page sizes, stream options) — every case has one width, so
+# both the fixed-width and the per-run-width site run it
+_RUN_CASES = {
+    "mixed_pages": ([7] * 4, [900, 700, 1100, 333], {}),
+    "one_rle_run": ([5], [1000], dict(rle_share=1.0, lo=1000, hi=1000)),
+    "one_bp_run": ([3], [64], dict(use_rle_runs=False)),
+    "rle_only": ([9] * 3, [800, 640, 515], dict(rle_share=1.0)),
+    "bp_only": ([11] * 3, [800, 640, 515],
+                dict(use_rle_runs=False, rle_share=0.0)),
+    "over_1024_runs": ([6] * 2, [16_000, 14_000],
+                       dict(rle_share=0.6, lo=8, hi=16)),
+}
+
+
+@pytest.mark.parametrize("site", ["hyb", "hybvw"])
+@pytest.mark.parametrize("case", sorted(_RUN_CASES))
+@pytest.mark.parametrize("layout", ["plain", "zero_length_runs",
+                                    "pad_to_count"])
+def test_run_expansion_matches_host(site, case, layout):
+    """Bit identity with the host decoder, tail lanes zero: every run shape
+    the reader hands the two XLA expansion sites."""
+    widths, sizes, kw = _RUN_CASES[case]
+    rng = np.random.default_rng(zlib.crc32(f"{case}/{layout}".encode()))
+    buf, tables, total, n_runs, host = _chunk_tables(
+        _encode_pages(rng, widths, sizes, **dict(kw)))
+    count = jd._bucket_count(total) + 24  # n_valid < count: a zeroed tail
+    if layout == "zero_length_runs":
+        tables = _with_zero_length_runs(tables, rng)
+    elif layout == "pad_to_count":
+        # padding runs end at the bucketed count (the Pallas plan's and
+        # the per-page tables' form), not at the real one
+        tables = [np.concatenate([t[:n_runs], np.zeros(6, t.dtype)])
+                  for t in tables]
+        tables[0][n_runs:] = count
+    if case.startswith("one_"):
+        assert n_runs == 1
+    if case == "over_1024_runs":
+        assert n_runs > 1024
+    mw = widths[0] if site == "hyb" else max(8, -(-widths[0] // 8) * 8)
+    out = np.asarray(_expand(site, buf, tables, total, count, mw))
+    assert out.shape == (count,)
+    np.testing.assert_array_equal(out[:total], host)
+    assert not out[total:].any()
+
+
+@pytest.mark.parametrize("width", range(1, 33))
+def test_run_expansion_fixed_widths(width):
+    """Every index width 1-32 through the fixed-width site (the dictionary
+    path of chunks whose pages share one width)."""
+    rng = np.random.default_rng(width)
+    buf, tables, total, _, host = _chunk_tables(
+        _encode_pages(rng, [width] * 3, [1500, 1000, 777]))
+    count = jd._bucket_count(total)
+    out = np.asarray(_expand("hyb", buf, tables, total, count, width))
+    np.testing.assert_array_equal(out[:total], host)
+    assert not out[total:].any()
+
+
+@pytest.mark.parametrize("widths", [(2, 3, 5, 8, 9, 13, 17),
+                                    (20, 24, 26, 31, 32), (1, 1, 2, 2, 3)])
+@pytest.mark.parametrize("layout", ["plain", "zero_length_runs"])
+def test_run_expansion_growing_widths(widths, layout):
+    """Per-page widths that grow as the dictionary fills: the per-run-width
+    site at the gather footprint the reader picks."""
+    rng = np.random.default_rng(sum(widths))
+    buf, tables, total, _, host = _chunk_tables(
+        _encode_pages(rng, widths, [700] * len(widths)))
+    if layout == "zero_length_runs":
+        tables = _with_zero_length_runs(tables, rng)
+    count = jd._bucket_count(total) + 40
+    mw = min(max(8, (max(widths) + 7) // 8 * 8), 32)
+    out = np.asarray(_expand("hybvw", buf, tables, total, count, mw))
+    np.testing.assert_array_equal(out[:total], host)
+    assert not out[total:].any()
+
+
+def test_run_broadcast_matches_search():
+    """run_broadcast gives lane i the fields of the run a right-sided search
+    of the ends finds (the last run past every end), bit for bit, for every
+    field dtype the sites pass: bool, uint8, uint32, int32, int64."""
+    rng = np.random.default_rng(5)
+    lens = rng.integers(0, 6, 300)
+    lens[rng.random(300) < 0.3] = 0
+    ends = np.cumsum(lens)
+    count = int(ends[-1]) + 17
+    ends = np.concatenate([ends, np.full(12, count + 3)])  # padding past count
+    fields = [rng.random(len(ends)) < 0.5,
+              rng.integers(0, 256, len(ends)).astype(np.uint8),
+              rng.integers(0, 1 << 32, len(ends), dtype=np.uint64)
+              .astype(np.uint32),
+              rng.integers(-(1 << 31), 1 << 31, len(ends)).astype(np.int32),
+              rng.integers(-(1 << 62), 1 << 62, len(ends))]
+    r = np.minimum(np.searchsorted(ends, np.arange(count), side="right"),
+                   len(ends) - 1)
+    with K.enable_x64():
+        got = K.run_broadcast(jnp.asarray(ends), [jnp.asarray(f)
+                                                   for f in fields], count)
+        for f, g in zip(fields, got):
+            assert g.dtype == f.dtype
+            np.testing.assert_array_equal(np.asarray(g), f[r])
+
+
+def _lowered_ops(fn, args, **static):
+    with K.enable_x64():
+        text = fn.lower(*args, **static).as_text()
+    return {op: len(re.findall(rf'"?stablehlo\.{op}"?[ (]', text))
+            for op in ("while", "gather")}
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("site,rp,mw", [
+    ("hyb", 16, 1), ("hyb", 256, 13), ("hyb", 1024, 32),
+    ("hybvw", 16, 8), ("hybvw", 1024, 16), ("hybvw", 16384, 24),
+    ("hybvw", 64, 32), ("combine", 16, 0), ("combine", 1024, 0),
+])
+def test_run_expansion_has_no_per_lane_lookup(site, rp, mw):
+    """Structural guard: the lowered expansion holds no loop (a per-lane
+    binary search lowers to one) and gathers only its payload — the byte
+    fetches of extract_bits, or the combine's one word gather — never a
+    run field per lane."""
+    from tpu_parquet import device_reader as dr
+
+    count, buf = 1 << 18, _sds((64 << 20,), jnp.uint8)
+    table = [_sds((rp,), dt) for dt in
+             (jnp.int64, jnp.bool_, jnp.uint32, jnp.int64, jnp.uint32)]
+    n_valid = _sds((), jnp.int64)
+    if site == "hyb":
+        ops = _lowered_ops(jd._hybrid_jit, (buf, *table[:4], n_valid),
+                           width=mw, count=count)
+    elif site == "hybvw":
+        ops = _lowered_ops(jd._hybrid_vw_jit, (buf, *table, n_valid),
+                           max_width=mw, count=count)
+    else:
+        ops = _lowered_ops(dr._hybrid_combine_staged_jit,
+                           (_sds((count,), jnp.uint32), buf,
+                            _sds((), jnp.int64), _sds((), jnp.int32)),
+                           count=count, rp=rp)
+    assert ops["while"] == 0
+    if site == "combine":
+        assert ops["gather"] == 1
+    else:
+        assert 1 <= ops["gather"] <= (mw + 14) // 8

@@ -233,3 +233,96 @@ def test_streaming_stager_multi_strip_parity(tmp_path, monkeypatch):
     for k in ref:
         for a, b in zip(ref[k], got[k]):
             np.testing.assert_array_equal(a, b)
+
+
+def _bp_rle_pages(rng, width, sizes, *, bp_only=False, lo=8, hi=120):
+    """[(meta, stream, n, host values)]: pages of constant stretches (RLE
+    runs) and random ones (bit-packed runs), decoded by the host decoder."""
+    from tpu_parquet.jax_decode import parse_hybrid_meta
+
+    pages = []
+    for n in sizes:
+        parts, total = [], 0
+        while total < n:
+            k = int(rng.integers(lo, hi + 1))
+            if rng.random() < 0.5:
+                parts.append(np.full(k, rng.integers(0, 1 << width),
+                                     dtype=np.uint64))
+            else:
+                parts.append(rng.integers(0, 1 << width, k, dtype=np.uint64))
+            total += k
+        vals = np.concatenate(parts)[:n]
+        enc = rle.encode(vals, width, use_rle_runs=not bp_only)
+        pages.append((parse_hybrid_meta(enc, width, n), enc, n,
+                      rle.decode(enc, width, n).astype(np.uint32)))
+    return pages
+
+
+def _insert_zero_length_runs(meta, rng, n_zero=12):
+    """Runs of length zero (garbage fields) among a page's real runs."""
+    k = meta.n_runs
+    at = np.sort(rng.integers(0, k, n_zero))
+    prev = np.where(at > 0, meta.run_ends[np.maximum(at - 1, 0)], 0)
+    meta.run_ends = np.insert(meta.run_ends[:k], at, prev)
+    meta.run_is_rle = np.insert(meta.run_is_rle[:k], at,
+                                rng.random(n_zero) < 0.5)
+    meta.run_values = np.insert(meta.run_values[:k], at,
+                                rng.integers(0, 1 << 30, n_zero)
+                                .astype(np.uint32))
+    meta.run_bit_starts = np.insert(meta.run_bit_starts[:k], at,
+                                    rng.integers(0, 1 << 16, n_zero))
+    meta.n_runs = k + n_zero
+
+
+# width, page sizes, options
+_COMBINE_CASES = {
+    "w1": (1, [900, 650], {}),
+    "w7": (7, [1200, 800, 333], {}),
+    "w13": (13, [1000], {}),
+    "w24": (24, [700, 700], {}),
+    "w32": (32, [512, 300], {}),
+    "one_bp_run": (5, [64], dict(bp_only=True)),
+    "bp_only": (9, [800, 640], dict(bp_only=True)),
+    "zero_length_runs": (6, [900, 700], dict(zero_length=True)),
+    "over_1024_runs": (10, [20_000, 18_000], dict(lo=8, hi=12)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COMBINE_CASES))
+def test_pallas_combine_matches_host(case, monkeypatch):
+    """The Pallas route's run-table combine (``_hybrid_combine_staged_jit``
+    behind ``_plan_hybrid_pallas``) is bit-identical to the host decoder,
+    with the bucketed tail lanes zero."""
+    from tpu_parquet.device_reader import (
+        _pallas_interpret_mode, _plan_hybrid_pallas, _RowGroupStager,
+    )
+    from tpu_parquet.jax_decode import _bucket_count
+    from tpu_parquet.jax_kernels import enable_x64
+
+    monkeypatch.setenv("TPQ_PALLAS", "1")
+    width, sizes, kw = _COMBINE_CASES[case]
+    kw = dict(kw)
+    zero_length = kw.pop("zero_length", False)
+    rng = np.random.default_rng(width * 1000 + sum(sizes))
+    pages = _bp_rle_pages(rng, width, sizes, **kw)
+    if zero_length:
+        for meta, _, _, _ in pages:
+            _insert_zero_length_runs(meta, rng)
+    n_runs = sum(m.n_runs for m, _, _, _ in pages)
+    if case == "one_bp_run":
+        assert n_runs == 1
+    if case == "over_1024_runs":
+        assert n_runs > 1024
+    total = sum(sizes)
+    count_pad = _bucket_count(total) + 40
+    stager = _RowGroupStager()
+    plan = _plan_hybrid_pallas(stager, [(m, s, n) for m, s, n, _ in pages],
+                               width, total, count_pad,
+                               _pallas_interpret_mode())
+    assert plan is not None and plan.run_expand == (1, n_runs, count_pad)
+    with enable_x64():
+        out = np.asarray(plan.fn(stager.stage(), *plan.dyn))
+    assert out.shape == (count_pad,)
+    np.testing.assert_array_equal(
+        out[:total], np.concatenate([h for _, _, _, h in pages]))
+    assert not out[total:].any()

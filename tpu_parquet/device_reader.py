@@ -800,24 +800,23 @@ def _hybrid_combine_staged_jit(vals, buf, tbase, n_valid, *, count, rp):
     """Combine Pallas-unpacked BP values with RLE runs into stream order.
 
     ``vals`` uint32[8 * groups_pad] — BP groups unpacked from the contiguous
-    staged payload, value-major (pallas_kernels.bp_value_index).  Every
-    output position finds its run with one searchsorted (same structure
-    as expand_rle_hybrid), then either
-    broadcasts the RLE value or picks its BP element at
-    ``bp_idx_base[run] + pos`` — one u32 gather instead of per-value
-    multi-byte extraction.  Run tables ride the staged buffer at ``tbase``
-    (layout [ends i32 | is_rle u8 | values u32 | bp_idx_base i32] × rp —
-    see _pack_tables); all index math is int32, so the trace is
-    x64-agnostic."""
+    staged payload, value-major (pallas_kernels.bp_value_index).  The run
+    fields reach every output position by ``K.run_broadcast`` (same
+    structure as expand_rle_hybrid: a scatter of the run starts and a
+    prefix sum, no per-lane search), then each position either takes its
+    RLE value or picks its BP element at ``bp_idx_base[run] + pos`` — one
+    u32 gather instead of per-value multi-byte extraction.  Run tables ride
+    the staged buffer at ``tbase`` (layout [ends i32 | is_rle u8 | values
+    u32 | bp_idx_base i32] × rp — see _pack_tables); all index math is
+    int32, so the trace is x64-agnostic."""
     ends = _tslice(buf, tbase, 0, rp, np.int32)
     isr = _tslice(buf, tbase, rp * 4, rp, np.uint8) != 0
     rvals = _tslice(buf, tbase, rp * 5, rp, np.uint32)
     bib = _tslice(buf, tbase, rp * 9, rp, np.int32)
     pos = jnp.arange(count, dtype=jnp.int32)
-    r = jnp.searchsorted(ends, pos, side="right").astype(jnp.int32)
-    r = jnp.minimum(r, rp - 1)
-    bp_idx = jnp.clip(bib[r] + pos, 0, vals.shape[0] - 1)
-    out = jnp.where(isr[r], rvals[r],
+    isr, rvals, bib = K.run_broadcast(ends, (isr, rvals, bib), count)
+    bp_idx = jnp.clip(bib + pos, 0, vals.shape[0] - 1)
+    out = jnp.where(isr, rvals,
                     vals[bp_value_index(bp_idx, vals.shape[0] // 8)])
     return jnp.where(pos < n_valid, out, jnp.zeros((), dtype=out.dtype))
 
@@ -910,6 +909,7 @@ def _plan_hybrid_pallas(stager: _RowGroupStager, pages_info, width: int,
         ("lvlp", width, gpad, rp, count_pad, bool(interpret)), fn,
         (np.int32(bp_base), np.int64(tbase), np.int32(total)), None,
         stages=2,  # pallas unpack pass + run-table combine pass
+        run_expand=(1, nr, count_pad),
     )
 
 
@@ -917,9 +917,11 @@ def _merge_run_tables(ends_l, rle_l, vals_l, starts_l, fill_end,
                       widths_l=None):
     """Pad per-page hybrid run lists into one bucketed chunk-global table.
 
-    Padding slots get ``run_ends = fill_end`` (so searchsorted clamps past
-    the real runs) and zeros elsewhere.  Returns (ends, is_rle, values,
-    starts[, widths]) — the argument set of expand_rle_hybrid(_vw).
+    Padding slots get ``run_ends = fill_end`` and zeros elsewhere, so they
+    reach only the tail lanes past the real runs, which ``n_valid`` masks
+    (``jax_kernels.run_broadcast`` gives each lane the run a search of the
+    ends would find).  Returns (ends, is_rle, values, starts[, widths]) —
+    the argument set of expand_rle_hybrid(_vw).
     """
     rp = _bucket(max(sum(len(e) for e in ends_l), 1))
     ends = np.full(rp, fill_end, dtype=np.int64)
@@ -1116,9 +1118,10 @@ class _Plan:
     """
 
     __slots__ = ("key", "fn", "dyn", "build", "route", "bytes_staged",
-                 "stages")
+                 "stages", "run_expand")
 
-    def __init__(self, key, fn, dyn, build, stages: "int | None" = None):
+    def __init__(self, key, fn, dyn, build, stages: "int | None" = None,
+                 run_expand: "tuple | None" = None):
         self.key = key
         self.fn = fn
         self.dyn = tuple(dyn)
@@ -1130,6 +1133,9 @@ class _Plan:
         self.bytes_staged = 0
         self.stages = (stages if stages is not None
                        else (3 if fn is not None else 0))
+        # hybrid run-table expansions the program runs: (expansions, real
+        # run entries, lanes) — the registry's ``device.run_expand``
+        self.run_expand = run_expand
 
 
 _FUSED_CACHE: dict = {}
@@ -1285,7 +1291,9 @@ def _run_plans(plans, buf_dev, stats: "DeviceStats | None" = None):
             stats.note_dispatch(dom.route or ROUTE_PLAIN, "fused_rg",
                                 _out_nbytes(results),
                                 sum(p.bytes_staged for _, p in traced),
-                                sum(p.stages for _, p in traced))
+                                sum(p.stages for _, p in traced),
+                                _sum_run_expand(p.run_expand
+                                                for _, p in traced))
         for (name, p), res in zip(traced, results):
             out[name] = p.build(res)
         return out
@@ -1295,9 +1303,18 @@ def _run_plans(plans, buf_dev, stats: "DeviceStats | None" = None):
         if stats is not None:
             stats.note_dispatch(p.route or ROUTE_PLAIN,
                                 _kernel_family(p.key), _out_nbytes(res),
-                                p.bytes_staged, p.stages)
+                                p.bytes_staged, p.stages, p.run_expand)
         out[name] = p.build(res)
     return out
+
+
+def _sum_run_expand(counts) -> "tuple | None":
+    """Element-wise sum of ``_Plan.run_expand`` tuples (None: no hybrid
+    expansion)."""
+    counts = [c for c in counts if c is not None]
+    if not counts:
+        return None
+    return tuple(sum(c[i] for c in counts) for i in range(3))
 
 
 def _compose_column(value_plan: "_Plan", d_plan, r_plan) -> "_Plan":
@@ -1325,6 +1342,8 @@ def _compose_column(value_plan: "_Plan", d_plan, r_plan) -> "_Plan":
     stages = (value_plan.stages
               + (d_plan.stages if d_plan is not None else 0)
               + (r_plan.stages if r_plan is not None else 0))
+    run_expand = _sum_run_expand(
+        p.run_expand for p in (value_plan, d_plan, r_plan) if p is not None)
 
     def build(res):
         vres, dres, rres = res
@@ -1335,7 +1354,7 @@ def _compose_column(value_plan: "_Plan", d_plan, r_plan) -> "_Plan":
             col.rep_levels = rres
         return col
 
-    return _Plan(key, fn, dyn, build, stages=stages)
+    return _Plan(key, fn, dyn, build, stages=stages, run_expand=run_expand)
 
 
 class _ChunkAssembler:
@@ -1889,6 +1908,7 @@ class _ChunkAssembler:
         bases = stager.add_segments(list(streams))
         ends_l, rle_l, vals_l, starts_l = [], [], [], []
         prefix = 0
+        n_runs = sum(meta.n_runs for meta in metas)
         for (src, start, size), base, p, meta in zip(streams, bases,
                                                      self.pages, metas):
             n = meta.n_runs
@@ -1912,7 +1932,8 @@ class _ChunkAssembler:
 
         return _Plan(("lvlx", width, slots_pad), fn,
                      (ends, is_rle, rvals, starts, np.int64(slots)), None,
-                     stages=2)  # run-table expand pass + tail-mask pass
+                     stages=2,  # run-table expand pass + tail-mask pass
+                     run_expand=(1, n_runs, slots_pad))
 
     def _value_segments(self, stager: _RowGroupStager) -> np.ndarray:
         """Register all pages' value streams back-to-back; returns byte bases
@@ -2568,6 +2589,8 @@ class _ChunkAssembler:
         has_u8 = dict_u8 is not None
         cp = _bucket_count(prefix)
         dyn: list = []
+        run_expand = (plan.run_expand if plan is not None
+                      else (1, sum(m.n_runs for _, _, m in parsed), cp))
         if plan is not None:
             idx_key, idx_fn, idx_arity = plan.key, plan.fn, len(plan.dyn)
             dyn.extend(plan.dyn)
@@ -2717,7 +2740,8 @@ class _ChunkAssembler:
                 deferred.append((res["max"], dict_len, path_name))
             return col
 
-        return _Plan(("dict", idx_key, dkey, need_max), fn, tuple(dyn), build)
+        return _Plan(("dict", idx_key, dkey, need_max), fn, tuple(dyn), build,
+                     run_expand=run_expand if prefix else None)
 
     def _finish_delta(self, common, stager):
         ptype = self.leaf.physical_type
@@ -2840,6 +2864,7 @@ class _ChunkAssembler:
         # one concat, ONE gather --------------------------------------------
         dict_calls = []  # (tables..., width, count)
         prefix = 0
+        n_runs = 0
         host_max = 0
         for p, base in zip(dict_pages, bases[:n_dict]):
             meta, width, _, host_max = self._parse_dict_index_page(p, host_max)
@@ -2848,6 +2873,7 @@ class _ChunkAssembler:
                 meta.run_bit_starts + int(base) * 8, int(width), p.defined,
             ))
             prefix += p.defined
+            n_runs += meta.n_runs
         self._check_dict_range(prefix, host_max)
 
         # --- plain suffix: contiguous bitcast when segments are exact -------
@@ -2934,6 +2960,7 @@ class _ChunkAssembler:
             ("mixed", name, dict_dtype, wc, bool(prefix), plain_desc,
              need_max),
             fn, tuple(dyn), build,
+            run_expand=((len(wc), n_runs, prefix) if wc else None),
         )
 
     def _finish_host(self, common):
@@ -3291,7 +3318,11 @@ class DeviceStats:
     returns, known from their shapes without waiting), ``bytes_staged``
     (link bytes staged for the columns) and ``device_passes`` (the
     structural pass count, see ``_Plan.stages``).  ``h2d`` counts the
-    staged row-group buffer transfers and their bytes.
+    staged row-group buffer transfers and their bytes.  ``run_expand``
+    counts the RLE/bit-packed hybrid expansions the dispatched programs
+    run (``jax_kernels.run_broadcast``): ``expansions``, the real run
+    entries they expand (``runs``, padding left out) and the output
+    ``lanes`` (bucketed counts, the work the device does).
 
     Device TIME is not here: it comes from a profiler capture, where each
     plan program runs as ``jit_tpq_<family>`` and the pipeline stages as
@@ -3304,9 +3335,11 @@ class DeviceStats:
         self._routes: dict = {}
         self._kernels: dict = {}
         self._h2d = [0, 0]  # transfers, bytes
+        self._run_expand = [0, 0, 0]  # expansions, runs, lanes
 
     def note_dispatch(self, route: str, family: str, bytes_out: int = 0,
-                      bytes_staged: int = 0, passes: int = 1) -> None:
+                      bytes_staged: int = 0, passes: int = 1,
+                      run_expand: "tuple | None" = None) -> None:
         with self._lock:
             for c in (self._routes.setdefault(route, [0, 0, 0, 0]),
                       self._kernels.setdefault(family, [0, 0, 0, 0])):
@@ -3314,6 +3347,9 @@ class DeviceStats:
                 c[1] += int(bytes_out)
                 c[2] += int(bytes_staged)
                 c[3] += int(passes)
+            if run_expand is not None:
+                for i, v in enumerate(run_expand):
+                    self._run_expand[i] += int(v)
 
     def note_h2d(self, nbytes: int = 0) -> None:
         with self._lock:
@@ -3350,6 +3386,8 @@ class DeviceStats:
                 "kernels": {fam: self._counters(k)
                             for fam, k in sorted(self._kernels.items())},
                 "h2d": {"transfers": self._h2d[0], "bytes": self._h2d[1]},
+                "run_expand": dict(zip(("expansions", "runs", "lanes"),
+                                       self._run_expand)),
             }
 
 

@@ -13,7 +13,9 @@ be per-value *arrays*.  Each value gathers the ≤5/≤9 bytes that can cover it
 combines them into a wide integer, shifts and masks.  This replaces the reference's
 98 width-specialized unrolled functions (bitbacking32.go / bitpacking64.go) and its
 value-at-a-time run loops (hybrid_decoder.go:81-113) with gathers the VPU executes
-8x128 lanes at a time.
+8x128 lanes at a time.  A run table reaches the lanes without any per-lane lookup
+into it: each run field is scattered at its run's start and prefix-summed over the
+lanes (`run_broadcast`), so the only per-lane gathers left are the payload bytes.
 
 All functions here are jit-compatible with static output shapes: ``count`` and
 padded run-table sizes are Python ints at trace time, so XLA sees fixed shapes and
@@ -85,6 +87,7 @@ __all__ = [
     "named_kernel",
     "extract_bits",
     "unpack_bits",
+    "run_broadcast",
     "expand_rle_hybrid",
     "expand_rle_hybrid_vw",
     "delta_reconstruct",
@@ -111,13 +114,15 @@ def extract_bits(buf: jax.Array, bit_pos: jax.Array, width: jax.Array, max_width
     ``buf``      uint8[n] — must be padded with >= (max_width+14)//8 slack bytes
                  so the trailing gathers stay in bounds (host pads; see
                  ``jax_decode.pad_buffer``).
-    ``bit_pos``  int32/int64[count] — starting bit of each field.
+    ``bit_pos``  int32/int64[count] — starting bit of each field; int32 stays
+                 int32 (the caller has checked that its positions fit).
     ``width``    scalar or per-value array — field width in bits (<= max_width).
     ``max_width`` static upper bound on width; selects the gather footprint.
 
     Returns uint32[count] when max_width <= 32, else uint64[count].
     """
-    bit_pos = bit_pos.astype(jnp.int64)
+    if bit_pos.dtype != jnp.int32:
+        bit_pos = bit_pos.astype(jnp.int64)
     byte0 = bit_pos >> 3
     shift = (bit_pos & 7).astype(jnp.uint32)
     nbytes = (max_width + 7 + 7) // 8  # widest field + worst-case 7-bit shift
@@ -186,6 +191,84 @@ def unpack_bits(buf: jax.Array, width: int, count: int):
 # RLE / bit-packed hybrid expansion
 # ---------------------------------------------------------------------------
 
+def _as_unsigned(x):
+    """``x`` as the unsigned integer type of its width (bits kept; bool as
+    0/1 in uint32), so differences and prefix sums wrap instead of
+    overflowing."""
+    if x.dtype == jnp.bool_ or x.dtype.itemsize < 4:
+        return x.astype(jnp.uint32)
+    ut = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    return x if x.dtype == ut else jax.lax.bitcast_convert_type(x, ut)
+
+
+def _from_unsigned(u, dt):
+    if dt == jnp.bool_:
+        return u != 0
+    if u.dtype.itemsize != jnp.dtype(dt).itemsize:
+        return u.astype(dt)
+    return u if u.dtype == dt else jax.lax.bitcast_convert_type(u, dt)
+
+
+def _lane_prefix_sum(x):
+    """Inclusive prefix sum of a 1-D lane array (wrapping), blocked in rows
+    of 128 lanes: a log-step scan along each row, then a scan of the row
+    totals.  The same sums as ``lax.cumsum``, whose TPU compile time grows
+    with the lane count (4.7 s at 262,144 lanes and 19.5 s at 1M for a v5e,
+    against about a second for this form)."""
+    n = x.shape[0]
+    rows = -(-n // 128)
+    m = jnp.pad(x, (0, rows * 128 - n)).reshape(rows, 128)
+    k = 1
+    while k < 128:
+        m = m + jnp.pad(m[:, :-k], ((0, 0), (k, 0)))
+        k *= 2
+    totals = m[:, -1]
+    carry = jax.lax.cumsum(totals) - totals  # exclusive: rows before each
+    return (m + carry[:, None]).reshape(-1)[:n]
+
+
+def run_broadcast(run_ends, fields, count: int):
+    """Give every lane of ``[0, count)`` the fields of the run that holds it.
+
+    ``run_ends`` int[R] is a sorted run table's cumulative ends; each of
+    ``fields`` is an [R] per-run column.  Lane ``i`` gets ``f[r]`` with
+    ``r = min(searchsorted(run_ends, i, side="right"), R - 1)``: the first
+    run ending past ``i``, the last run for lanes past every end.
+
+    A segment broadcast, with no per-lane lookup into the run table: run
+    ``k`` starts where run ``k - 1`` ends (run 0 at lane 0), the table
+    difference ``f[k] - f[k - 1]`` is scatter-added at that start, and a
+    prefix sum over the lanes telescopes back to ``f[r]``.  Differences and
+    sums wrap in unsigned arithmetic, so every bit pattern survives.  A run
+    of length zero shares its start with the next run and cancels out;
+    starts at or past ``count`` (padding runs at ``fill_end``) fall off the
+    scatter.  Work per field: R scatter updates and one prefix sum over
+    ``count`` lanes, where a binary search costs log2(R) gathers per lane.
+    Each field gets its own 1-D scatter and scan: on a v5e that beat one
+    scatter and scan over the fields stacked.
+    """
+    starts = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32),
+        jnp.minimum(run_ends[:-1], count).astype(jnp.int32),
+    ])
+    out = []
+    for f in fields:
+        u = _as_unsigned(f)
+        diff = u - jnp.concatenate([jnp.zeros((1,), u.dtype), u[:-1]])
+        lanes = jnp.zeros((count,), u.dtype).at[starts].add(
+            diff, mode="drop", indices_are_sorted=True)
+        out.append(_from_unsigned(_lane_prefix_sum(lanes), f.dtype))
+    return out
+
+
+def _lane_index_dtype(buf, count: int, max_width: int):
+    """int32 when every bit position the expansion forms fits (the staged
+    buffer's bits plus a run's ``count * max_width`` rebase either way),
+    else int64.  Decided by static shapes: no option."""
+    span = buf.shape[0] * 8 + 2 * count * max(int(max_width), 1)
+    return jnp.int32 if span < (1 << 31) else jnp.int64
+
+
 @named_kernel("unpack")
 @scoped_x64
 def expand_rle_hybrid(
@@ -217,18 +300,20 @@ def expand_rle_hybrid(
                        minus run_start*width so position math is uniform (0 for RLE).
     ``width``          static bit width of the stream.
 
-    Replaces hybridDecoder.next (hybrid_decoder.go:81-113): every output position
-    finds its run with one searchsorted, then either broadcasts the RLE value or
-    bit-extracts its element — no sequential state.
+    Replaces hybridDecoder.next (hybrid_decoder.go:81-113): the run fields
+    reach every output position by :func:`run_broadcast` (a scatter of the
+    run starts and a prefix sum, no per-lane search of the table), then each
+    position either takes its RLE value or bit-extracts its element — no
+    sequential state.  Bit positions are int32 where the buffer's size
+    allows (:func:`_lane_index_dtype`).
     """
-    pos = jnp.arange(count, dtype=jnp.int64)
-    r = jnp.searchsorted(run_ends, pos, side="right").astype(jnp.int32)
-    r = jnp.minimum(r, run_ends.shape[0] - 1)
-    is_rle = run_is_rle[r]
-    rle_val = run_values[r]
     if width == 0:
         return jnp.zeros(count, dtype=jnp.uint32)
-    bit_pos = run_bit_starts[r] + pos * width
+    it = _lane_index_dtype(buf, count, width)
+    pos = jnp.arange(count, dtype=it)
+    is_rle, rle_val, start = run_broadcast(
+        run_ends, (run_is_rle, run_values, run_bit_starts.astype(it)), count)
+    bit_pos = start + pos * width
     # clamp BP gathers for RLE positions to 0 so they stay in bounds
     bit_pos = jnp.where(is_rle, 0, bit_pos)
     bp_val = extract_bits(buf, bit_pos, width, width)
@@ -260,15 +345,17 @@ def expand_rle_hybrid_vw(
     static lets one executable decode the whole chunk's merged run table —
     per-value dynamic widths are what :func:`extract_bits` is built for.
     ``max_width`` is the static gather-footprint bound (round it to a
-    multiple of 8 to share executables).
+    multiple of 8 to share executables).  The width rides
+    :func:`run_broadcast` as a fourth run field.
     """
-    pos = jnp.arange(count, dtype=jnp.int64)
-    r = jnp.searchsorted(run_ends, pos, side="right").astype(jnp.int32)
-    r = jnp.minimum(r, run_ends.shape[0] - 1)
-    is_rle = run_is_rle[r]
-    rle_val = run_values[r]
-    w = run_widths[r].astype(jnp.int64)
-    bit_pos = run_bit_starts[r] + pos * w
+    it = _lane_index_dtype(buf, count, max_width)
+    pos = jnp.arange(count, dtype=it)
+    is_rle, rle_val, start, w = run_broadcast(
+        run_ends,
+        (run_is_rle, run_values, run_bit_starts.astype(it), run_widths),
+        count)
+    w = w.astype(it)
+    bit_pos = start + pos * w
     bit_pos = jnp.where(is_rle, 0, bit_pos)
     bp_val = extract_bits(buf, bit_pos, w.astype(jnp.uint32), max_width)
     out = jnp.where(is_rle, rle_val.astype(bp_val.dtype), bp_val)

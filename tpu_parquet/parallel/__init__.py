@@ -221,7 +221,7 @@ def pack_hybrid_pages(
     for m, c in zip(metas, per_page):
         if c < count:  # pad: one RLE run of zeros fills the tail
             # run_ends stays sorted: real runs end ≤ c, bucket padding == c,
-            # the synthetic run == count, so searchsorted routes tail slots here
+            # the synthetic run == count, so the tail slots fall in this run
             m.run_ends = np.concatenate([m.run_ends, [count]]).astype(np.int64)
             m.run_is_rle = np.concatenate([m.run_is_rle, [True]])
             m.run_values = np.concatenate([m.run_values, [0]]).astype(np.uint32)
